@@ -28,27 +28,37 @@ from repro.models import cnn
 from repro.optim import sgd_nesterov, paper_step_decay
 
 PE_TYPES = ("fp32", "int16", "lightpe1", "lightpe2")
+QAT_DEPTH = 8      # ResNet depth of the QAT runs
+QAT_BATCH = 64     # images per training step
 
 
-def train_acc(pe: str, depth: int = 8, steps: int = 200, trials: int = 2):
+def qat_setup(pe: str, trial: int = 0, depth: int = QAT_DEPTH):
+    """Initial ``(params, opt_state, step)`` of one QAT run under PE type
+    ``pe``'s numerics; ``step(params, opt_state, batch)`` is the jitted
+    training step and returns ``(params, opt_state, loss)``."""
+    params = cnn.resnet_init(jax.random.PRNGKey(trial), depth=depth,
+                             n_classes=10)
+    opt = sgd_nesterov(paper_step_decay(0.02, 80), weight_decay=5e-4)
+
+    @jax.jit
+    def step(params, ostate, batch):
+        (loss, acc), grads = jax.value_and_grad(
+            lambda p: cnn.cnn_loss(cnn.resnet_apply, p, batch, pe),
+            has_aux=True)(params)
+        params, ostate = opt.update(grads, ostate, params)
+        return params, ostate, loss
+
+    return params, opt.init(params), step
+
+
+def train_acc(pe: str, depth: int = QAT_DEPTH, steps: int = 200,
+              trials: int = 2):
     accs = []
     for trial in range(trials):
-        key = jax.random.PRNGKey(trial)
-        params = cnn.resnet_init(key, depth=depth, n_classes=10)
-        opt = sgd_nesterov(paper_step_decay(0.02, 80), weight_decay=5e-4)
-        ostate = opt.init(params)
-
-        @jax.jit
-        def step(params, ostate, batch):
-            (loss, acc), grads = jax.value_and_grad(
-                lambda p: cnn.cnn_loss(cnn.resnet_apply, p, batch, pe),
-                has_aux=True)(params)
-            params, ostate = opt.update(grads, ostate, params)
-            return params, ostate, loss
-
+        params, ostate, step = qat_setup(pe, trial, depth)
         for i in range(steps):
             params, ostate, _ = step(params, ostate,
-                                     image_batch(trial, i, 64, 10))
+                                     image_batch(trial, i, QAT_BATCH, 10))
         ev = eval_image_set(0, 512, 10)
         logits = cnn.resnet_apply(params, ev["images"], pe)
         accs.append(float(jnp.mean(

@@ -134,6 +134,9 @@ def main() -> None:
                          "sweep_report.json / metrics.json here")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import common
     if args.telemetry_dir:
         os.makedirs(args.telemetry_dir, exist_ok=True)

@@ -264,9 +264,10 @@ class JointWalk(NamedTuple):
     walk, the sharded pipeline and the frontserver's coalesced query walk
     all iterate ``chunks()``, so for the same plan parameters they visit
     the IDENTICAL chunk sequence — the structural anchor behind the
-    bit-identity contracts across drivers.  Mixed-mode plans carry the
-    layer-bucket grouping (one stacked workload / compiled evaluator per
-    bucket); per-model plans walk one model at a time.
+    bit-identity contracts across drivers.  Both modes evaluate through
+    the per-bucket stacked workloads (one compiled evaluator per bucket):
+    mixed-mode chunks interleave a bucket's models, per-model chunks walk
+    one model at a time with a constant ``model_ids``.
     """
     models: tuple
     space: dict | None
@@ -275,10 +276,10 @@ class JointWalk(NamedTuple):
     seed: int
     mix_models: bool
     group_ids: tuple | None        # mixed: bucket -> global model id tuple
-    bucket_of: tuple | None        # mixed: model id -> padded bucket depth
-    stacked: dict | None           # mixed: bucket depth -> StackedWorkload
-    local: np.ndarray | None       # mixed: global id -> position in stack
-    buckets_meta: tuple = ()       # (padded depth, model names) per group
+    bucket_of: tuple               # model id -> padded bucket depth
+    stacked: dict                  # bucket depth -> StackedWorkload
+    local: np.ndarray              # global id -> position in its stack
+    buckets_meta: tuple = ()       # mixed: (padded depth, model names)
 
     def chunks(self, start_chunk: int = 0):
         """Yield ``(wl_key, workload, model_ids, mids, cfg, idx)`` from
@@ -301,15 +302,16 @@ class JointWalk(NamedTuple):
                 seed=self.seed, group_by_model=True,
                 start_chunk=start_chunk):
             mids = np.full(len(idx), int(m), np.int64)
-            yield int(m), self.models[m].workload, None, mids, cfg, idx
+            yield (int(m), self.stacked[self.bucket_of[m]],
+                   self.local[mids], mids, cfg, idx)
 
     def workload_for(self, wl_key):
         """The (stacked) workload behind a ``chunks()`` key — checkpoint
         restore of an interrupted pruner buffer."""
         if wl_key is None:
             return None
-        return self.stacked[int(wl_key)] if self.mix_models \
-            else self.models[int(wl_key)].workload
+        return self.stacked[int(wl_key) if self.mix_models
+                            else self.bucket_of[int(wl_key)]]
 
 
 def plan_joint_walk(models: Sequence[ModelEntry],
@@ -323,16 +325,15 @@ def plan_joint_walk(models: Sequence[ModelEntry],
     freeze every enumeration parameter, so multiple drivers — or repeated
     passes of one driver — replay the exact same chunk stream."""
     models = tuple(models)
-    bucket_of = group_ids = stacked = local = None
-    buckets_meta = ()
-    if mix_models:
-        bucket_of, group_ids, stacked, local, buckets_meta = \
-            _bucket_models(models, layer_buckets)
+    bucket_of, group_ids, stacked, local, buckets_meta = \
+        _bucket_models(models, layer_buckets)
+    if not mix_models:
+        group_ids, buckets_meta = None, ()
     return JointWalk(models=models, space=space, chunk_size=int(chunk_size),
                      max_points=max_points, seed=int(seed),
                      mix_models=bool(mix_models), group_ids=group_ids,
-                     bucket_of=None if bucket_of is None else tuple(bucket_of),
-                     stacked=stacked, local=local, buckets_meta=buckets_meta)
+                     bucket_of=tuple(bucket_of), stacked=stacked,
+                     local=local, buckets_meta=buckets_meta)
 
 
 def coexplore_front(

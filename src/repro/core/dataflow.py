@@ -33,7 +33,8 @@ import jax.numpy as jnp
 from repro.core import energy as E
 from repro.core import pe as PE
 from repro.core.arch import AcceleratorConfig
-from repro.core.workloads import KIND_ATTN_KV, KIND_MOE_EXPERT, LayerSpec
+from repro.core.workloads import (KIND_ATTN_KV, KIND_MOE_EXPERT, LayerSpec,
+                                  ceil_div, floor_div)
 
 
 class LayerCost(NamedTuple):
@@ -53,7 +54,7 @@ class LayerCost(NamedTuple):
 
 
 def _ceil_div(a, b):
-    return jnp.ceil(a / jnp.maximum(b, 1.0))
+    return ceil_div(a, jnp.maximum(b, 1.0))
 
 
 def _mapping_knobs(mapping):
@@ -68,17 +69,19 @@ def _mapping_knobs(mapping):
         tile caps (smaller tiles trade RF pressure for spill traffic).
 
     Mixed radix 3 x 2 x 4 x 5 = 120 codes; code 0 decodes to the exact
-    legacy schedule (0.5 split, rows-first, divisors 1).
+    legacy schedule (0.5 split, rows-first, divisors 1).  Decoded in
+    integer arithmetic, so no backend's divide can shift a digit.
     """
-    m = jnp.asarray(mapping, jnp.float32)
-    split_code = jnp.mod(m, 3.0)                       # 0 -> 0.5 (legacy)
-    fil_frac = jnp.where(split_code == 1.0, 0.75,
-                         jnp.where(split_code == 2.0, 0.25, 0.5))
-    cols_first = jnp.mod(jnp.floor(m / 3.0), 2.0) == 1.0
-    c_div = 2.0 ** jnp.mod(jnp.floor(m / 6.0), 4.0)    # 1, 2, 4, 8
-    q_code = jnp.mod(jnp.floor(m / 24.0), 5.0)
-    q_div = jnp.where(q_code == 4.0, 6.0, q_code + 1.0)  # 1, 2, 3, 4, 6
-    return m == 0.0, fil_frac, cols_first, c_div, q_div
+    m = jnp.asarray(mapping).astype(jnp.int32)
+    split_code = m % 3                                 # 0 -> 0.5 (legacy)
+    fil_frac = jnp.where(split_code == 1, 0.75,
+                         jnp.where(split_code == 2, 0.25, 0.5))
+    cols_first = (m // 3) % 2 == 1
+    c_div = jnp.left_shift(1, (m // 6) % 4).astype(jnp.float32)  # 1,2,4,8
+    q_code = (m // 24) % 5
+    q_div = jnp.where(q_code == 4, 6.0,
+                      (q_code + 1).astype(jnp.float32))  # 1, 2, 3, 4, 6
+    return m == 0, fil_frac, cols_first, c_div, q_div
 
 
 def layer_cost(layer: LayerSpec, cfg: AcceleratorConfig,
@@ -113,8 +116,8 @@ def layer_cost(layer: LayerSpec, cfg: AcceleratorConfig,
     streamed = layer.kind == float(KIND_ATTN_KV)
     gated = layer.kind == float(KIND_MOE_EXPERT)
     active_frac = jnp.maximum(layer.active_frac, 1e-9)
-    Eh = jnp.floor((H - R) / stride) + 1.0
-    F = jnp.floor((W - S) / stride) + 1.0
+    Eh = floor_div(H - R, stride) + 1.0
+    F = floor_div(W - S, stride) + 1.0
     macs = batch * K * C * R * S * Eh * F * count
 
     a_bits = PE.act_bits(cfg.pe_type)
@@ -130,12 +133,12 @@ def layer_cost(layer: LayerSpec, cfg: AcceleratorConfig,
     # mapped codes cap the channel/filter tiles below capacity (c_div /
     # q_div): less RF residency per PE, more replication groups and spill
     c_fit = jnp.where(
-        legacy, jnp.clip(jnp.floor(cfg.spad_ifmap / S), 1.0, C),
-        jnp.clip(jnp.floor(cfg.spad_ifmap / (S * c_div)), 1.0, C))
-    q_cap = jnp.floor(cfg.spad_filter / (c_fit * S))
+        legacy, jnp.clip(floor_div(cfg.spad_ifmap, S), 1.0, C),
+        jnp.clip(floor_div(cfg.spad_ifmap, S * c_div), 1.0, C))
+    q_cap = floor_div(cfg.spad_filter, c_fit * S)
     q_fit = jnp.where(
         legacy, jnp.clip(jnp.minimum(q_cap, cfg.spad_psum), 1.0, K),
-        jnp.clip(jnp.minimum(jnp.floor(q_cap / q_div), cfg.spad_psum),
+        jnp.clip(jnp.minimum(floor_div(q_cap, q_div), cfg.spad_psum),
                  1.0, K))
 
     # ---- spatial mapping: logical R x E grid onto pe_rows x pe_cols ----
@@ -148,8 +151,8 @@ def layer_cost(layer: LayerSpec, cfg: AcceleratorConfig,
     # PEs; the mapping's loop-order bit picks which array dimension gets
     # first claim on the group supply (legacy: rows first)
     groups = _ceil_div(K, q_fit) * _ceil_div(C, c_fit) * batch
-    repl_r_cap = jnp.floor(Pr / jnp.maximum(rows_used, 1.0))
-    repl_c_cap = jnp.floor(Pc / jnp.maximum(cols_used, 1.0))
+    repl_r_cap = floor_div(Pr, jnp.maximum(rows_used, 1.0))
+    repl_c_cap = floor_div(Pc, jnp.maximum(cols_used, 1.0))
     repl_r_first = jnp.clip(repl_r_cap, 1.0, groups)
     repl_c_rest = jnp.clip(repl_c_cap, 1.0,
                            jnp.maximum(groups / repl_r_first, 1.0))
@@ -177,17 +180,17 @@ def layer_cost(layer: LayerSpec, cfg: AcceleratorConfig,
     # ifmap tile (legacy: an even 0.5/0.5 split; mapped codes re-split)
     k_fit_gbuf = jnp.where(
         legacy,
-        jnp.clip(jnp.floor(0.5 * gbuf_bits_cap /
+        jnp.clip(floor_div(0.5 * gbuf_bits_cap,
                            jnp.maximum(C * R * S * w_bits, 1.0)), 1.0, K),
-        jnp.clip(jnp.floor(fil_frac * gbuf_bits_cap /
+        jnp.clip(floor_div(fil_frac * gbuf_bits_cap,
                            jnp.maximum(C * R * S * w_bits, 1.0)), 1.0, K))
     replay_if = _ceil_div(K, k_fit_gbuf)
     # ifmaps (batch granularity) that fit in the remaining share
     n_if_fit = jnp.where(
         legacy,
-        jnp.clip(jnp.floor(0.5 * gbuf_bits_cap /
+        jnp.clip(floor_div(0.5 * gbuf_bits_cap,
                            jnp.maximum(C * H * W * a_bits, 1.0)), 1.0, batch),
-        jnp.clip(jnp.floor((1.0 - fil_frac) * gbuf_bits_cap /
+        jnp.clip(floor_div((1.0 - fil_frac) * gbuf_bits_cap,
                            jnp.maximum(C * H * W * a_bits, 1.0)), 1.0, batch))
     replay_fil = _ceil_div(batch, n_if_fit)
     # second-operand DRAM stream: resident weights replay with gbuf

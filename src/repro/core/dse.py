@@ -43,7 +43,8 @@ from repro.core.costmodel import CostModel, as_cost_model
 from repro.core.dataflow import layer_cost, reduce_layer_costs
 from repro.core.ppa import PPAModels
 from repro.core.synth import LEAKAGE_MW_PER_MM2
-from repro.core.workloads import StackedWorkload, Workload
+from repro.core.workloads import (StackedWorkload, Workload, layer_bucket,
+                                  pad_layers, workload_layers)
 from repro.obs import as_tracer, timed_iter
 
 # Default number of design points evaluated per jit call in the streaming
@@ -137,9 +138,11 @@ def _compile_mark() -> int:
 
 
 def _workload_bucket(workload) -> str:
-    # (M, L) stacked or (L,) plain: the trailing axis is the padded layer
-    # count — exactly the thing the bucketed evaluators compile per.
-    return f"L{int(np.shape(workload.layers.H)[-1])}"
+    # the padded layer count the evaluator compiles per: a stack's
+    # trailing axis, or the bucket a plain workload is padded to
+    if isinstance(workload, StackedWorkload):
+        return f"L{int(np.shape(workload.layers.H)[-1])}"
+    return f"L{layer_bucket(workload_layers(workload))}"
 
 
 def _note_compiles(tr, mark: int, start_ns: int, workload,
@@ -184,29 +187,34 @@ def _traced_finish(tr, pending: "PendingChunk",
 
 
 @jax.jit
-def _network_sums(cfg: AcceleratorConfig, clock_ghz: jnp.ndarray, layers):
-    """Summed network cost per design-point lane (the jitted hot path).
+def _lane_layers(stacked_layers, model_ids: jnp.ndarray):
+    """Each lane's own layer stack, gathered from the (M, L) pytree.
 
-    Per-layer costs are computed for all (lane, layer) pairs first, then
-    reduced OUTSIDE the vmap with the optimization barrier in place — the
-    structure that makes results a bit-identical function of the layer
-    values regardless of padded depth (see ``reduce_layer_costs``).
-    """
-    _count_trace()
-    per_layer = jax.vmap(
-        lambda c, clk: jax.vmap(layer_cost, in_axes=(0, None, None))(
-            layers, c, clk))(cfg, clock_ghz)      # leaves (lanes, L)
-    return reduce_layer_costs(per_layer, layers.count, barrier=True)
+    Pure data movement, compiled apart from the arithmetic: the evaluator
+    below receives the same (lanes, L) operands whether a chunk mixes
+    models, repeats one model, or broadcasts a plain workload — so a
+    lane's value cannot depend on how its chunk was grouped."""
+    return jax.tree.map(lambda x: x[model_ids], stacked_layers)
+
+
+@partial(jax.jit, static_argnums=(1, 2))
+def _broadcast_layers(layers, lanes: int, depth: int):
+    """A plain (L,) workload padded to ``depth`` and repeated per lane —
+    the one-model case of ``_lane_layers``."""
+    return jax.tree.map(lambda x: jnp.broadcast_to(x, (lanes, depth)),
+                        pad_layers(layers, depth))
 
 
 @jax.jit
-def _network_sums_mixed(cfg: AcceleratorConfig, clock_ghz: jnp.ndarray,
-                        stacked_layers, model_ids: jnp.ndarray):
-    """Model-lane batched evaluation: each lane gathers its own layer stack
-    from the (M, L) pytree, so one compiled executable serves chunks that
-    freely mix models (the one-compile joint sweep)."""
+def _network_sums(cfg: AcceleratorConfig, clock_ghz: jnp.ndarray,
+                  lane_layers):
+    """Summed network cost per design-point lane (the jitted hot path).
+
+    ``lane_layers`` holds every lane's own layer stack, leaves (lanes,
+    L).  Every walk reaches this one executable per (layer bucket, chunk
+    shape) — mixed-model, per-model and plain walks alike — so walks that
+    visit the same lane compute it with the same compiled code."""
     _count_trace()
-    lane_layers = jax.tree.map(lambda x: x[model_ids], stacked_layers)
     per_layer = jax.vmap(
         lambda lay, c, clk: jax.vmap(layer_cost, in_axes=(0, None, None))(
             lay, c, clk))(lane_layers, cfg, clock_ghz)  # leaves (lanes, L)
@@ -263,10 +271,18 @@ def _ppa_stage(ppa_fn, params, cfg: AcceleratorConfig):
 
 def _network_stage(cfg: AcceleratorConfig, clock_ghz,
                    workload: Workload | StackedWorkload, model_ids=None):
-    """Dispatch the dataflow fold (the compiled per-bucket evaluator)."""
+    """Dispatch the dataflow fold (the compiled per-bucket evaluator).
+
+    A plain workload is padded to its ``layer_bucket`` depth, the depth
+    ``stack_workloads`` gives it by default, so it shares the bucket's
+    executable with every stacked walk."""
     if model_ids is not None:
-        return _network_sums_mixed(cfg, clock_ghz, workload.layers, model_ids)
-    return _network_sums(cfg, clock_ghz, workload.layers)
+        lanes = _lane_layers(workload.layers, model_ids)
+    else:
+        lanes = _broadcast_layers(workload.layers,
+                                  int(np.shape(cfg.pe_rows)[0]),
+                                  layer_bucket(workload_layers(workload)))
+    return _network_sums(cfg, clock_ghz, lanes)
 
 
 class PendingChunk(NamedTuple):
@@ -328,7 +344,9 @@ def evaluate_chunk(cfg: AcceleratorConfig,
     gathers its own layer stack inside the jitted function, so chunks
     crossing model boundaries still share one compilation per (chunk
     shape, stacked depth).  Lane results are bit-identical to evaluating
-    each lane under its own unpadded workload.
+    each lane under its own plain workload when the stack's depth is
+    that workload's ``layer_bucket``: both then run the same compiled
+    evaluator on the same per-lane layer rows.
     """
     return finish_chunk(dispatch_chunk(cfg, workload, surrogate,
                                        pad_to=pad_to, model_ids=model_ids))
@@ -1052,8 +1070,9 @@ class ParetoArchive:
     Feed ``update(objectives, indices)`` chunk-by-chunk; the archive keeps
     exactly the points that would be non-dominated in the concatenation of
     everything seen so far (same semantics as the dense oracle on the full
-    matrix — duplicates of a non-dominated point are all retained).  State
-    is O(front size); the full objective matrix is never held.
+    matrix — duplicates of a non-dominated point are all retained), with
+    rows in ascending index order.  State is O(front size); the full
+    objective matrix is never held.
     """
 
     def __init__(self, num_objectives: int):
@@ -1167,10 +1186,12 @@ class ParetoArchive:
             # own reduction, so the merge only evicts archive points a
             # new candidate dominates
             keep_old = ~_dominated_by(self._obj, obj)
-            self._obj = np.concatenate([self._obj[keep_old], obj])
-            self._idx = np.concatenate([self._idx[keep_old], idx])
-        else:
-            self._obj, self._idx = obj, idx
+            obj = np.concatenate([self._obj[keep_old], obj])
+            idx = np.concatenate([self._idx[keep_old], idx])
+        # rows in ascending index order: a front's row order then depends
+        # only on its point set, never on the order a walk visited chunks
+        order = np.argsort(idx, kind="stable")
+        self._obj, self._idx = obj[order], idx[order]
 
 
 def pareto_front_streaming(

@@ -31,9 +31,13 @@ from __future__ import annotations
 
 import itertools
 from dataclasses import dataclass, field
+from functools import partial
+from math import comb
 from typing import Dict, Sequence
 
+import jax
 import jax.numpy as jnp
+from jax.scipy.linalg import solve_triangular
 import numpy as np
 
 from repro.core.arch import AcceleratorConfig, PE_TYPE_NAMES
@@ -60,12 +64,34 @@ def monomial_exponents(n_features: int, degree: int) -> np.ndarray:
     return np.array(exps, dtype=np.int32)
 
 
+# Contractions of the fit and the prediction: full f32 on every backend
+# (the TPU's default f32 matmul takes bf16 passes, too coarse for the
+# normal equations of a degree-3 fit).
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _max_degree(n_monomials: int, n_features: int) -> int:
+    """Total degree of a ``monomial_exponents`` basis, from its size."""
+    d = 0
+    while comb(n_features + d, d) < n_monomials:
+        d += 1
+    return d
+
+
 def design_matrix(x: jnp.ndarray, exps: np.ndarray,
                   mu: jnp.ndarray, sigma: jnp.ndarray) -> jnp.ndarray:
-    """Monomial basis on standardized features. x: (N, F) -> (N, M)."""
+    """Monomial basis on standardized features. x: (N, F) -> (N, M).
+
+    Powers are repeated products, not ``pow``: standardized features are
+    often negative, and a float ``pow`` lowers through ``log`` on some
+    backends."""
     z = (x - mu) / sigma
-    # (N, 1, F) ** (1, M, F) -> prod over F -> (N, M)
-    return jnp.prod(z[:, None, :] ** jnp.asarray(exps)[None, :, :], axis=-1)
+    e = jnp.asarray(exps)[None, :, :]                  # (1, M, F)
+    zb = jnp.broadcast_to(z[:, None, :], (z.shape[0],) + e.shape[1:])
+    p = jnp.ones_like(zb)
+    for k in range(_max_degree(e.shape[1], e.shape[2])):
+        p = jnp.where(e > k, p * zb, p)
+    return jnp.prod(p, axis=-1)
 
 
 @dataclass
@@ -80,31 +106,66 @@ class PolyModel:
 
     def predict(self, x: jnp.ndarray) -> jnp.ndarray:
         a = design_matrix(x, self.exps, self.mu, self.sigma)
-        y = a @ self.coef
+        y = jnp.matmul(a, self.coef, precision=_HIGHEST)
         return jnp.exp(y) if self.log_target else y
 
 
 def _fit_coef(a: jnp.ndarray, y: jnp.ndarray, ridge: float = 1e-6):
+    """``argmin |a c - y|^2 + ridge |c|^2`` by QR of ``[a; sqrt(ridge) I]``.
+
+    Not the normal equations: they square the design's condition number,
+    which a degree-2 or -3 basis on ~100 rows takes past float32's reach,
+    so their solution turns on rounding that differs between backends."""
     m = a.shape[1]
-    ata = a.T @ a + ridge * jnp.eye(m)
-    return jnp.linalg.solve(ata, a.T @ y)
+    aug = jnp.concatenate([a, jnp.sqrt(ridge) * jnp.eye(m, dtype=a.dtype)])
+    rhs = jnp.concatenate([y, jnp.zeros(m, y.dtype)])
+    with jax.default_matmul_precision("highest"):   # the QR's own dots
+        q, r = jnp.linalg.qr(aug)
+        return solve_triangular(
+            r, jnp.matmul(q.T, rhs, precision=_HIGHEST), lower=False)
+
+
+# The fit is compiled per (sample size, degree), and its rows are chosen
+# by 0/1 weights rather than by slicing: every fold of every PE type's
+# cross-validation then reuses one executable per degree.
+
+@jax.jit
+def _standardize(x, w):
+    """Weighted feature mean and standard deviation of the rows w selects
+    (one executable, so every target of a sample standardizes alike)."""
+    n = jnp.sum(w)
+    mu = jnp.sum(w[:, None] * x, axis=0) / n
+    var = jnp.sum(w[:, None] * (x - mu) ** 2, axis=0) / n
+    return mu, jnp.maximum(jnp.sqrt(var), 1e-6)
+
+
+@partial(jax.jit, static_argnames=("degree", "log_target"))
+def _fit_arrays(x, y, w, mu, sigma, ridge, degree, log_target):
+    a = design_matrix(x, monomial_exponents(x.shape[1], degree), mu, sigma)
+    t = jnp.log(jnp.maximum(y, 1e-12)) if log_target else y
+    return _fit_coef(a * w[:, None], t * w, ridge)
 
 
 def fit_poly(x: jnp.ndarray, y: jnp.ndarray, degree: int,
-             log_target: bool = True, ridge: float = 1e-6) -> PolyModel:
-    mu = jnp.mean(x, axis=0)
-    sigma = jnp.maximum(jnp.std(x, axis=0), 1e-6)
-    exps = monomial_exponents(x.shape[1], degree)
-    a = design_matrix(x, exps, mu, sigma)
-    t = jnp.log(jnp.maximum(y, 1e-12)) if log_target else y
-    coef = _fit_coef(a, t, ridge)
-    return PolyModel(degree=degree, exps=exps, mu=mu, sigma=sigma, coef=coef,
-                     log_target=log_target)
+             log_target: bool = True, ridge: float = 1e-6,
+             weights=None) -> PolyModel:
+    """Ridge fit of one polynomial on the rows ``weights`` (0/1, default
+    all) selects."""
+    x = jnp.asarray(x, jnp.float32)
+    y = jnp.asarray(y, jnp.float32)
+    w = jnp.ones(x.shape[0], jnp.float32) if weights is None \
+        else jnp.asarray(weights, jnp.float32)
+    mu, sigma = _standardize(x, w)
+    coef = _fit_arrays(x, y, w, mu, sigma, ridge, degree, log_target)
+    return PolyModel(degree=degree,
+                     exps=monomial_exponents(x.shape[1], degree), mu=mu,
+                     sigma=sigma, coef=coef, log_target=log_target)
 
 
 def kfold_mse(x: jnp.ndarray, y: jnp.ndarray, degree: int, k: int = 5,
-              log_target: bool = True) -> float:
-    """k-fold CV mean squared error (in log space if log_target).
+              log_target: bool = True, weights=None) -> float:
+    """k-fold CV mean squared error (in log space if log_target) over the
+    rows ``weights`` (0/1, default all) selects.
 
     ``k`` is clamped to the sample count: with k > n, np.array_split
     would yield empty folds whose MSE is a mean over an empty array
@@ -112,7 +173,9 @@ def kfold_mse(x: jnp.ndarray, y: jnp.ndarray, degree: int, k: int = 5,
     ``select_and_fit`` (NaN compares False, so the first degree always
     won).  Cross-validation needs at least 2 samples.
     """
-    n = int(x.shape[0])
+    rows = np.arange(int(x.shape[0])) if weights is None \
+        else np.flatnonzero(np.asarray(weights))
+    n = len(rows)
     if n < 2:
         raise ValueError(f"kfold_mse needs >= 2 samples to hold one out, "
                          f"got {n}")
@@ -120,30 +183,32 @@ def kfold_mse(x: jnp.ndarray, y: jnp.ndarray, degree: int, k: int = 5,
     idx = np.arange(n)
     rng = np.random.default_rng(0)
     rng.shuffle(idx)
-    folds = np.array_split(idx, k)
+    folds = np.array_split(rows[idx], k)
+    y_host = np.asarray(y, np.float64)
     errs = []
     for f in folds:
-        mask = np.ones(n, bool)
+        mask = np.zeros(int(x.shape[0]), bool)
+        mask[rows] = True
         mask[f] = False
-        model = fit_poly(x[mask], y[mask], degree, log_target)
-        pred = model.predict(x[f])
-        t, p = (np.log(np.maximum(np.asarray(y[f]), 1e-12)),
-                np.log(np.maximum(np.asarray(pred), 1e-12))) \
-            if log_target else (np.asarray(y[f]), np.asarray(pred))
+        model = fit_poly(x, y, degree, log_target, weights=mask)
+        pred = np.asarray(model.predict(x))[f]
+        t, p = (np.log(np.maximum(y_host[f], 1e-12)),
+                np.log(np.maximum(pred, 1e-12))) \
+            if log_target else (y_host[f], pred)
         errs.append(float(np.mean((t - p) ** 2)))
     return float(np.mean(errs))
 
 
 def select_and_fit(x: jnp.ndarray, y: jnp.ndarray,
                    degrees: Sequence[int] = (1, 2, 3), k: int = 5,
-                   log_target: bool = True) -> PolyModel:
+                   log_target: bool = True, weights=None) -> PolyModel:
     """Model selection by k-fold CV (the paper's methodology), then refit."""
     best_d, best_mse = degrees[0], float("inf")
     for d in degrees:
-        mse = kfold_mse(x, y, d, k, log_target)
+        mse = kfold_mse(x, y, d, k, log_target, weights)
         if mse < best_mse:
             best_d, best_mse = d, mse
-    return fit_poly(x, y, best_d, log_target)
+    return fit_poly(x, y, best_d, log_target, weights=weights)
 
 
 def surrogate_ppa(params, cfg: AcceleratorConfig):
@@ -189,10 +254,12 @@ def surrogate_ppa(params, cfg: AcceleratorConfig):
         for entry, a in zip(params["types"], shared):
             if a is not None:
                 coef, log = entry["targets"][t]
-                v = a[:, :coef.shape[0]] @ coef     # prefix-sliced basis
+                v = jnp.matmul(a[:, :coef.shape[0]], coef,   # prefix basis
+                               precision=_HIGHEST)
             else:
                 exps, mu, sigma, coef, log = entry[t]
-                v = design_matrix(x, exps, mu, sigma) @ coef
+                v = jnp.matmul(design_matrix(x, exps, mu, sigma), coef,
+                               precision=_HIGHEST)
             preds.append(jnp.where(log, jnp.exp(v), v))
         stacked = jnp.stack(preds)                  # (fitted types, N)
         out.append(jnp.take_along_axis(stacked, pos[None, :], axis=0)[0])
@@ -360,7 +427,8 @@ def fit_ppa_models(cfg: AcceleratorConfig,
         if not sel.any():
             continue
         models[name] = {
-            t: select_and_fit(x[sel], jnp.atleast_1d(ys[t])[sel], degrees, k)
+            t: select_and_fit(x, jnp.atleast_1d(ys[t]), degrees, k,
+                              weights=sel)
             for t in TARGETS}
     return PPAModels(models=models)
 

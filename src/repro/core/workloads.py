@@ -54,6 +54,27 @@ ACC_CLASSES = ("default", "attn", "ffn", "expert")
 ACC_DEFAULT, ACC_ATTN, ACC_FFN, ACC_EXPERT = range(len(ACC_CLASSES))
 
 
+def floor_div(a, b):
+    """``floor(a / b)`` for ``b > 0``, exact on every backend wherever
+    ``a`` and ``b`` are integers with products below 2**24.
+
+    Such quotients are often whole numbers (96 / 32), and a divide that
+    is not correctly rounded — the TPU's f32 divide — may land one ulp
+    below one, which ``floor`` would turn into the next integer down.  Two
+    compare-and-step corrections restore the exact floor; on a correctly
+    rounded backend they never fire for integer operands."""
+    q = jnp.floor(a / b)
+    q = jnp.where(q * b > a, q - 1.0, q)
+    return jnp.where((q + 1.0) * b <= a, q + 1.0, q)
+
+
+def ceil_div(a, b):
+    """``ceil(a / b)`` for ``b > 0``, exact like ``floor_div``."""
+    q = jnp.ceil(a / b)
+    q = jnp.where((q - 1.0) * b >= a, q - 1.0, q)
+    return jnp.where(q * b < a, q + 1.0, q)
+
+
 class LayerSpec(NamedTuple):
     """One conv layer: input HxWxC, K filters of RxS, given stride & batch.
 
@@ -86,8 +107,8 @@ class LayerSpec(NamedTuple):
     acc_class: jnp.ndarray = 0.0     # ACC_CLASSES code
 
     def out_hw(self):
-        E = jnp.floor((self.H - self.R) / self.stride) + 1.0
-        F = jnp.floor((self.W - self.S) / self.stride) + 1.0
+        E = floor_div(self.H - self.R, self.stride) + 1.0
+        F = floor_div(self.W - self.S, self.stride) + 1.0
         return E, F
 
     def macs(self):
@@ -524,6 +545,16 @@ def workload_layers(wl: Workload) -> int:
     return int(np.shape(wl.layers.H)[0])
 
 
+def pad_layers(layers: LayerSpec, n_layers: int) -> LayerSpec:
+    """Append zero-cost ``_PAD_ROW`` layers to a stacked (L,) LayerSpec
+    up to ``n_layers`` (jit-safe: the depth is a static shape)."""
+    pad = n_layers - int(np.shape(layers.H)[0])
+    return LayerSpec(*[
+        jnp.concatenate([getattr(layers, f),
+                         jnp.full((pad,), _PAD_ROW[f], jnp.float32)])
+        for f in LayerSpec._fields])
+
+
 def pad_workload(wl: Workload, n_layers: int) -> Workload:
     """Pad a workload to ``n_layers`` with zero-cost (count=0) layers.
 
@@ -531,11 +562,9 @@ def pad_workload(wl: Workload, n_layers: int) -> Workload:
     0.0 to every summed cost field and weight 0 to the MAC-weighted
     utilization, so ``network_cost`` of the padded workload is
     BIT-IDENTICAL to the unpadded oracle under eager execution and under
-    any fixed compiled evaluator shape.  (Comparing across two *different*
-    jit-compiled shapes can still see <=1-ulp noise from XLA's
-    shape-dependent FMA/vectorization choices in the per-layer kernel —
-    which is exactly why the joint engine buckets depths to a few
-    canonical shapes instead of padding each model to its own length.)
+    any fixed compiled evaluator shape.  The DSE evaluator pads every
+    workload to its ``layer_bucket`` depth, so walks that are compared
+    with each other run one compiled shape per bucket.
     Idempotent for ``n_layers`` equal to the current depth; refuses to
     truncate.
     """
@@ -545,13 +574,9 @@ def pad_workload(wl: Workload, n_layers: int) -> Workload:
                          f"{n_layers}")
     if n_layers == n:
         return wl
-    pad = n_layers - n
-    layers = LayerSpec(*[
-        jnp.concatenate([getattr(wl.layers, f),
-                         jnp.full((pad,), _PAD_ROW[f], jnp.float32)])
-        for f in LayerSpec._fields])
-    names = wl.layer_names + tuple(f"pad{i}" for i in range(pad))
-    return Workload(name=wl.name, layers=layers, layer_names=names)
+    names = wl.layer_names + tuple(f"pad{i}" for i in range(n_layers - n))
+    return Workload(name=wl.name, layers=pad_layers(wl.layers, n_layers),
+                    layer_names=names)
 
 
 def layer_bucket(n_layers: int,

@@ -26,14 +26,14 @@ DEFAULT_BN = 256
 
 
 def _affine_kernel(w_ref, s_ref, o_ref, *, qmax):
-    s = s_ref[...][None, :]
+    s = s_ref[...]
     q = jnp.clip(jnp.round(w_ref[...] / s), -qmax, qmax)
     o_ref[...] = q * s
 
 
 def _pow2_kernel(w_ref, emax_ref, o_ref):
     w = w_ref[...]
-    e_max = emax_ref[...][None, :]
+    e_max = emax_ref[...]
     e_min = e_max - (POW2_LEVELS - 1)
     mag = jnp.maximum(jnp.abs(w), 1e-12)
     e = jnp.clip(jnp.round(jnp.log2(mag)), e_min, e_max)
@@ -50,7 +50,8 @@ def fake_quant(w: jnp.ndarray, scale: jnp.ndarray, *, mode: str = "affine",
     assert k % bk == 0 and n % bn == 0, (k, n, bk, bn)
     grid = (k // bk, n // bn)
     w_spec = pl.BlockSpec((bk, bn), lambda i, j: (i, j))
-    s_spec = pl.BlockSpec((bn,), lambda i, j: (j,))
+    # (N,) scale as a (1, N) row: see quant_matmul's scale block
+    s_spec = pl.BlockSpec((1, bn), lambda i, j: (0, j))
     if mode == "affine":
         kernel = functools.partial(_affine_kernel,
                                    qmax=2.0 ** (bits - 1) - 1.0)
@@ -65,7 +66,7 @@ def fake_quant(w: jnp.ndarray, scale: jnp.ndarray, *, mode: str = "affine",
         out_specs=w_spec,
         out_shape=jax.ShapeDtypeStruct((k, n), w.dtype),
         interpret=interpret,
-    )(w, scale)
+    )(w, scale.reshape(1, n))
 
 
 def _round_up(x: int, m: int) -> int:

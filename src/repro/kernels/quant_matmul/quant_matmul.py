@@ -39,7 +39,10 @@ DEFAULT_BK = 256  # unpacked K elements per step (128 packed rows)
 
 
 def _unpack_tile(wp, bk):
-    """(bk//2, bn) uint8 -> (bk, bn) uint8 codes, rows (2r, 2r+1)."""
+    """(bk//2, bn) uint8 -> (bk, bn) int32 codes, rows (2r, 2r+1).
+
+    Widened to int32 first: Mosaic has no 8-bit vector shifts."""
+    wp = wp.astype(jnp.int32)
     lo = wp & 0xF
     hi = (wp >> 4) & 0xF
     inter = jnp.stack([lo, hi], axis=1)           # (bk//2, 2, bn)
@@ -54,15 +57,14 @@ def _mm_kernel_int4(x_ref, wp_ref, scale_ref, o_ref, acc_ref, *, bk, nk):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     codes = _unpack_tile(wp_ref[...], bk)
-    q = codes.astype(jnp.int8)
-    q = jnp.where(q >= 8, q - 16, q).astype(jnp.float32)   # sign-extend 4b
+    q = jnp.where(codes >= 8, codes - 16, codes).astype(jnp.float32)  # 4b
     acc_ref[...] += jax.lax.dot(
         x_ref[...].astype(jnp.float32), q,
         preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _write():
-        o_ref[...] = acc_ref[...] * scale_ref[...][None, :]
+        o_ref[...] = acc_ref[...] * scale_ref[...]
 
 
 def _mm_kernel_pow2(x_ref, wp_ref, emax_ref, o_ref, acc_ref, *, bk, nk):
@@ -82,7 +84,7 @@ def _mm_kernel_pow2(x_ref, wp_ref, emax_ref, o_ref, acc_ref, *, bk, nk):
 
     @pl.when(k == nk - 1)
     def _write():
-        o_ref[...] = acc_ref[...] * jnp.exp2(emax_ref[...] - 7.0)[None, :]
+        o_ref[...] = acc_ref[...] * jnp.exp2(emax_ref[...] - 7.0)
 
 
 def _mm_kernel_int8(x_ref, w_ref, scale_ref, o_ref, acc_ref, *, nk):
@@ -98,7 +100,7 @@ def _mm_kernel_int8(x_ref, w_ref, scale_ref, o_ref, acc_ref, *, nk):
 
     @pl.when(k == nk - 1)
     def _write():
-        o_ref[...] = acc_ref[...] * scale_ref[...][None, :]
+        o_ref[...] = acc_ref[...] * scale_ref[...]
 
 
 @functools.partial(jax.jit,
@@ -127,7 +129,9 @@ def quant_matmul(x: jnp.ndarray, w: jnp.ndarray, scale: jnp.ndarray,
         w_spec = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
     else:
         raise ValueError(f"unknown mode {mode}")
-    s_spec = pl.BlockSpec((bn,), lambda i, j, k: (j,))
+    # the (N,) scale rides as a (1, N) row: a 1-D block must match the
+    # whole array's tiling on the TPU, a row block only the lane width
+    s_spec = pl.BlockSpec((1, bn), lambda i, j, k: (0, j))
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
 
     kernel = {"int4": functools.partial(_mm_kernel_int4, bk=bk, nk=nk),
@@ -142,4 +146,4 @@ def quant_matmul(x: jnp.ndarray, w: jnp.ndarray, scale: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, w, scale)
+    )(x, w, scale.reshape(1, n))

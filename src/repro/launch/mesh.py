@@ -9,6 +9,7 @@ on the single real CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,12 +17,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis (the multi-pod dry-run proves the pod axis shards)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (elastic-restore targets, tests)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (training, elastic-restore targets, tests).
+
+    Every axis is ``Auto``: the training step leaves propagation to XLA
+    and annotates shardings with ``with_sharding_constraint``, which
+    ``jax.make_mesh``'s default Explicit axes would reject."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> tuple:

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import get as get_cfg, reduced as get_reduced, list_archs
 from repro.data import lm_pipeline
+from repro.launch.mesh import make_mesh
 from repro.models import family_module
 from repro.models.layers import activation_sharding
 from repro.optim import adamw, sgd_nesterov, warmup_cosine
@@ -57,8 +58,8 @@ def main(argv=None):
     if n_dev > 1:
         model_par = max(d for d in (1, 2, 4, 8, 16) if n_dev % d == 0
                         and cfg.n_heads % d == 0) if cfg.n_heads else 1
-        mesh = jax.make_mesh((n_dev // model_par, model_par),
-                             ("data", "model"))
+        mesh = make_mesh((n_dev // model_par, model_par),
+                         ("data", "model"))
         dp = ("data",)
 
     opt = {"adamw": adamw(warmup_cosine(args.lr, 20, args.steps)),
@@ -74,8 +75,8 @@ def main(argv=None):
     state = None
     if args.resume and args.ckpt_dir:
         state = trainer.resume(cfg, mod, opt,
-                               mesh or jax.make_mesh((1, 1),
-                                                     ("data", "model")),
+                               mesh or make_mesh((1, 1),
+                                                 ("data", "model")),
                                args.ckpt_dir, pipe, key)
     if state is None:
         state = trainer.init_state(cfg, mod, opt, key)

@@ -106,6 +106,33 @@ class TestSynth:
                   for p in ("fp32", "int16", "lightpe2", "lightpe1")]
         assert clocks == sorted(clocks)
 
+    def test_noise_is_the_same_compiled_or_not(self):
+        """The noise hash is integer arithmetic: fusing it under jit
+        cannot change a bit of it; the factor stays within +-3% and moves
+        by no more than an ulp."""
+        import jax
+        from repro.core.synth import _hash_units, _noise
+        space = enumerate_space(max_points=2000, seed=0)
+        for salt in (1, 2, 3):
+            for eager, fused in zip(
+                    _hash_units(space, salt),
+                    jax.jit(_hash_units, static_argnums=1)(space, salt)):
+                np.testing.assert_array_equal(eager, fused)
+            eager = np.asarray(_noise(space, salt))
+            fused = np.asarray(jax.jit(_noise, static_argnums=1)(space, salt))
+            np.testing.assert_allclose(fused, eager, rtol=2.4e-7, atol=0)
+            assert np.all(np.abs(eager - 1.0) <= 0.03)
+            assert np.std(eager) > 0.01
+
+    def test_log2_within_an_ulp(self):
+        from repro.core.synth import _log2
+        x = np.concatenate([np.arange(2, 5000, dtype=np.float32),
+                            np.geomspace(2, 2 ** 30, 5000, dtype=np.float32)])
+        got = np.asarray(_log2(jnp.asarray(x)), np.float64)
+        want = np.log2(x.astype(np.float64))
+        ulp = np.spacing(want.astype(np.float32)).astype(np.float64)
+        assert np.max(np.abs(got - want) / ulp) <= 1.0
+
 
 class TestPPAFit:
     def test_fit_quality(self):

@@ -218,8 +218,8 @@ class TestCompileAccounting:
         coexplore_front(tiny_models, TINY_SPACE, chunk_size=CHUNK,
                         surrogate=ppa_models)
         assert trace_count() == 0 and ppa_trace_count() == 0
-        from repro.core.dse import _network_sums_mixed, _ppa_stage
-        _network_sums_mixed.clear_cache()
+        from repro.core.dse import _network_sums, _ppa_stage
+        _network_sums.clear_cache()
         _ppa_stage.clear_cache()
         reset_trace_count()
         front = coexplore_front(tiny_models, TINY_SPACE, chunk_size=CHUNK,
@@ -238,8 +238,8 @@ class TestCompileAccounting:
         assert "resnet20-cifar10-r224" in names
         buckets = {layer_bucket(workload_layers(m.workload)) for m in models}
         assert buckets == {16, 32, 64}
-        from repro.core.dse import _network_sums_mixed, _ppa_stage
-        _network_sums_mixed.clear_cache()
+        from repro.core.dse import _network_sums, _ppa_stage
+        _network_sums.clear_cache()
         _ppa_stage.clear_cache()
         reset_trace_count()
         front = coexplore_front(models, TINY_SPACE, chunk_size=CHUNK,

@@ -251,8 +251,8 @@ class TestServingSweepBitIdentity:
                 for m in models} == {16, 32, 64}
 
     def test_compile_count_is_bucket_count(self, serving_models):
-        from repro.core.dse import _network_sums_mixed, _ppa_stage
-        _network_sums_mixed.clear_cache()
+        from repro.core.dse import _network_sums, _ppa_stage
+        _network_sums.clear_cache()
         _ppa_stage.clear_cache()
         reset_trace_count()
         front = coexplore_front(serving_models, TINY_SPACE, chunk_size=CHUNK)

@@ -13,6 +13,7 @@ from repro.checkpoint import latest_step
 from repro.configs import reduced
 from repro.data import lm_pipeline
 from repro.data.synthetic import eval_image_set, image_batch, token_batch
+from repro.launch.mesh import make_mesh
 from repro.models import cnn, family_module
 from repro.optim import adamw, paper_step_decay, sgd_nesterov, warmup_cosine
 from repro.serve import ServeEngine, dequantize_params, quantize_params
@@ -67,7 +68,7 @@ class TestFaultTolerance:
         mod = family_module(cfg)
         opt = adamw(warmup_cosine(1e-3, 5, 100))
         step = jax.jit(make_train_step(cfg, mod, opt, n_micro=1))
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
 
         state_a = init_state(cfg, mod, opt, jax.random.PRNGKey(0))
         pipe_a = lm_pipeline(cfg, global_batch=4, seq=32)
@@ -95,7 +96,7 @@ class TestFaultTolerance:
         mod = family_module(cfg)
         params = mod.init_params(cfg, jax.random.PRNGKey(0))
         ckpt.save(tmp_ckpt, 1, params)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         shardings = make_param_shardings(
             cfg, jax.eval_shape(lambda: params), mesh, "train")
         restored, _, _ = ckpt.restore(tmp_ckpt, 1, params,
@@ -123,7 +124,7 @@ class TestGradCompression:
         from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim.grad_compress import compressed_psum_mean
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         g = jnp.asarray(np.random.default_rng(0).normal(size=(64,)),
                         jnp.float32)
         err = jnp.zeros_like(g)
