@@ -25,6 +25,8 @@ from typing import Iterable, Iterator, NamedTuple, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import as_tracer
+
 # PE type codes (index into the constant tables in pe.py).
 PE_FP32 = 0
 PE_INT16 = 1
@@ -206,29 +208,34 @@ def subsample_indices(n: int, max_points: int | None,
     return np.sort(rng.choice(n, size=max_points, replace=False))
 
 
-def _cols_to_config(cols: dict) -> AcceleratorConfig:
-    return AcceleratorConfig(
-        pe_rows=jnp.asarray(cols["pe_rows"], jnp.float32),
-        pe_cols=jnp.asarray(cols["pe_cols"], jnp.float32),
-        gbuf_kb=jnp.asarray(cols["gbuf_kb"], jnp.float32),
-        spad_ifmap=jnp.asarray(cols["spad_ifmap"], jnp.float32),
-        spad_filter=jnp.asarray(cols["spad_filter"], jnp.float32),
-        spad_psum=jnp.asarray(cols["spad_psum"], jnp.float32),
-        pe_type=jnp.asarray(cols["pe_type"], jnp.int32),
-        bandwidth_gbps=jnp.asarray(cols["bandwidth_gbps"], jnp.float32),
-        mapping=jnp.asarray(cols["mapping"], jnp.float32),
-    )
+def _cols_to_config(cols: dict, telemetry=None) -> AcceleratorConfig:
+    """The host columns copied to the device, under a ``copy.upload``
+    span."""
+    with as_tracer(telemetry).span("upload", cat="copy"):
+        return AcceleratorConfig(
+            pe_rows=jnp.asarray(cols["pe_rows"], jnp.float32),
+            pe_cols=jnp.asarray(cols["pe_cols"], jnp.float32),
+            gbuf_kb=jnp.asarray(cols["gbuf_kb"], jnp.float32),
+            spad_ifmap=jnp.asarray(cols["spad_ifmap"], jnp.float32),
+            spad_filter=jnp.asarray(cols["spad_filter"], jnp.float32),
+            spad_psum=jnp.asarray(cols["spad_psum"], jnp.float32),
+            pe_type=jnp.asarray(cols["pe_type"], jnp.int32),
+            bandwidth_gbps=jnp.asarray(cols["bandwidth_gbps"], jnp.float32),
+            mapping=jnp.asarray(cols["mapping"], jnp.float32),
+        )
 
 
 def space_points(indices: np.ndarray,
-                 space: dict | None = None) -> AcceleratorConfig:
+                 space: dict | None = None,
+                 telemetry=None) -> AcceleratorConfig:
     """Decode flat space indices into a batched config via mixed radix.
 
     Index order matches ``itertools.product`` over the fields in
     ``AcceleratorConfig._fields`` order (last axis varies fastest), so
     ``space_points(np.arange(space_size()))`` reproduces the historical
     ``enumerate_space()`` exactly — but any index subset decodes in O(len)
-    without materializing the grid.
+    without materializing the grid.  ``telemetry=`` times the copy of the
+    decoded columns to the device (``copy.upload``).
     """
     axes = _space_axes(space)
     idx = np.asarray(indices, np.int64)
@@ -238,14 +245,15 @@ def space_points(indices: np.ndarray,
     keys = AcceleratorConfig._fields
     cols = {k: axes[i][(idx // strides[i]) % radices[i]]
             for i, k in enumerate(keys)}
-    return _cols_to_config(cols)
+    return _cols_to_config(cols, telemetry)
 
 
 def iter_space_chunks(space: dict | None = None,
                       chunk_size: int = 4096,
                       max_points: int | None = None,
                       seed: int = 0,
-                      start_chunk: int = 0) -> Iterator[
+                      start_chunk: int = 0,
+                      telemetry=None) -> Iterator[
                           tuple[AcceleratorConfig, np.ndarray]]:
     """Lazily yield ``(config_chunk, flat_indices)`` pairs over the space.
 
@@ -258,18 +266,19 @@ def iter_space_chunks(space: dict | None = None,
     ``start_chunk`` skips the first N chunks WITHOUT decoding them — the
     resume primitive of checkpointed walks: chunk boundaries are a pure
     function of ``(space, chunk_size, max_points, seed)``, so skipping is
-    index arithmetic, not re-evaluation.
+    index arithmetic, not re-evaluation.  ``telemetry=`` reaches
+    ``space_points``.
     """
     n = space_size(space)
     keep = subsample_indices(n, max_points, seed)
     if keep is not None:
         for lo in range(start_chunk * chunk_size, len(keep), chunk_size):
             idx = keep[lo:lo + chunk_size]
-            yield space_points(idx, space), idx
+            yield space_points(idx, space, telemetry), idx
         return
     for lo in range(start_chunk * chunk_size, n, chunk_size):
         idx = np.arange(lo, min(lo + chunk_size, n), dtype=np.int64)
-        yield space_points(idx, space), idx
+        yield space_points(idx, space, telemetry), idx
 
 
 def enumerate_space(space: dict | None = None,
@@ -344,6 +353,7 @@ def iter_joint_space_chunks(
         group_by_model: bool = False,
         model_groups: Sequence[Sequence[int]] | None = None,
         start_chunk: int = 0,
+        telemetry=None,
 ) -> Iterator[tuple[int | np.ndarray, AcceleratorConfig, np.ndarray]]:
     """Lazily yield ``(model_ids, config_chunk, flat_joint_indices)``.
 
@@ -368,7 +378,8 @@ def iter_joint_space_chunks(
     ``start_chunk`` skips the first N chunks of the walk (counted in
     yield order) without decoding them — whole model/group segments are
     skipped by chunk-count arithmetic, so resume cost is O(max_points)
-    index bookkeeping, never re-evaluation.
+    index bookkeeping, never re-evaluation.  ``telemetry=`` reaches
+    ``space_points``.
     """
     a = space_size(space)
     n = joint_space_size(space, num_models)
@@ -386,7 +397,7 @@ def iter_joint_space_chunks(
                 continue
             for lo in range(skip * chunk_size, len(midx), chunk_size):
                 idx = midx[lo:lo + chunk_size]
-                yield m, space_points(idx - m * a, space), idx
+                yield m, space_points(idx - m * a, space, telemetry), idx
             skip = 0
         return
     if model_groups is None:
@@ -406,7 +417,8 @@ def iter_joint_space_chunks(
             for lo in range(skip * chunk_size, g_n, chunk_size):
                 loc = np.arange(lo, min(lo + chunk_size, g_n), dtype=np.int64)
                 mids = g[loc // a]
-                yield mids, space_points(loc % a, space), mids * a + loc % a
+                yield (mids, space_points(loc % a, space, telemetry),
+                       mids * a + loc % a)
             skip = 0
         else:
             gidx = keep[np.isin(keep // a, g)]
@@ -416,7 +428,7 @@ def iter_joint_space_chunks(
                 continue
             for lo in range(skip * chunk_size, len(gidx), chunk_size):
                 idx = gidx[lo:lo + chunk_size]
-                yield idx // a, space_points(idx % a, space), idx
+                yield idx // a, space_points(idx % a, space, telemetry), idx
             skip = 0
 
 

@@ -217,6 +217,37 @@ def _update_per_model_best(best: dict, models: tuple, acc_matrix: np.ndarray,
                                          float(-obj[sel, 2].max()))
 
 
+def _fold_joint(tr, archive, best: dict, models: tuple,
+                acc_matrix: np.ndarray, res, idx, mids, codes, lane_acc=None,
+                budget=None, stats=None, track=None):
+    """Fold one evaluated joint chunk: its objectives (span
+    ``objectives``), the budget mask and archive (``fold_budget_chunk``),
+    then the (model, PE-type) bests of the rows that reached the archive
+    (span ``best``).  The one fold of every joint driver — walk, two-stage
+    flush, sharded walk, search — so all share one host arithmetic, and
+    row masking commutes with both the archive reduction and the bests.
+    ``lane_acc`` defaults to the ``acc_matrix`` gather.  Returns the
+    chunk's objectives and the indices that reached the archive."""
+    with tr.span("objectives", track=track):
+        if lane_acc is None:
+            lane_acc = acc_matrix[mids, codes]
+        obj = _joint_objectives(res, lane_acc)
+    m_obj, m_idx, (m_mids, m_codes) = fold_budget_chunk(
+        archive, obj, idx, result=res, budget=budget, accuracy=lane_acc,
+        stats=stats, aux=(mids, codes), telemetry=tr, track=track)
+    with tr.span("best", track=track):
+        _update_per_model_best(best, models, acc_matrix, m_mids, m_codes,
+                               m_obj)
+    return obj, m_idx
+
+
+def _pe_codes(tr, cfg) -> np.ndarray:
+    """A decoded chunk's PE-type codes read back from the device (span
+    ``copy.codes``)."""
+    with tr.span("codes", cat="copy"):
+        return np.asarray(cfg.pe_type).astype(np.int64)
+
+
 def _bucket_models(models: tuple, layer_buckets):
     """Group the model axis into layer-count buckets for the one-compile
     mixed walk.  Returns ``(bucket_of, group_ids, stacked, local,
@@ -281,18 +312,18 @@ class JointWalk(NamedTuple):
     local: np.ndarray              # global id -> position in its stack
     buckets_meta: tuple = ()       # mixed: (padded depth, model names)
 
-    def chunks(self, start_chunk: int = 0):
+    def chunks(self, start_chunk: int = 0, telemetry=None):
         """Yield ``(wl_key, workload, model_ids, mids, cfg, idx)`` from
         ``start_chunk`` on — resumable by index arithmetic, identical
         sequences across drivers.  ``wl_key`` names the workload (bucket
         depth when mixing, model id otherwise) for pruner/checkpoint
-        state."""
+        state.  ``telemetry=`` reaches the decode's ``space_points``."""
         if self.mix_models:
             for mids, cfg, idx in iter_joint_space_chunks(
                     self.space, num_models=len(self.models),
                     chunk_size=self.chunk_size, max_points=self.max_points,
                     seed=self.seed, model_groups=self.group_ids,
-                    start_chunk=start_chunk):
+                    start_chunk=start_chunk, telemetry=telemetry):
                 b = self.bucket_of[int(mids[0])]
                 yield b, self.stacked[b], self.local[mids], mids, cfg, idx
             return
@@ -300,7 +331,7 @@ class JointWalk(NamedTuple):
                 self.space, num_models=len(self.models),
                 chunk_size=self.chunk_size, max_points=self.max_points,
                 seed=self.seed, group_by_model=True,
-                start_chunk=start_chunk):
+                start_chunk=start_chunk, telemetry=telemetry):
             mids = np.full(len(idx), int(m), np.int64)
             yield (int(m), self.stacked[self.bucket_of[m]],
                    self.local[mids], mids, cfg, idx)
@@ -409,9 +440,10 @@ def coexplore_front(
     truncates the walk (preemption for kill/resume tests).
 
     ``telemetry=`` (a ``repro.obs.Tracer``) instruments the walk —
-    decode/dispatch/device-wait/archive spans, budget kill counters,
-    pruner stage split — without touching evaluated values; the front is
-    bit-identical with it on or off.
+    walk_setup/decode/dispatch/device-wait/objectives/archive/best spans
+    with the copies and the archive prefilter nested in them, budget kill
+    counters, pruner stage split — without touching evaluated values; the
+    front is bit-identical with it on or off.
 
     ``driver`` (a ``search.SearchDriver`` or registered name like
     ``"evolve"``/``"halving"``) replaces enumeration with BUDGETED
@@ -464,20 +496,21 @@ def coexplore_front(
             checkpoint_every=checkpoint_every, csv_path=csv_path,
             max_chunks=max_chunks, telemetry=telemetry)
     tr = as_tracer(telemetry)
-    cost_model = as_cost_model(surrogate)
-    acc_matrix = accuracy_matrix(models, accuracy)
-    walk = plan_joint_walk(models, space=space, chunk_size=chunk_size,
-                           max_points=max_points, seed=seed,
-                           mix_models=mix_models,
-                           layer_buckets=layer_buckets)
-    archive = ParetoArchive(len(COEXPLORE_METRICS))
-    per_model_best: dict[tuple[str, str], dict] = {}
-    stats = BudgetStats() if budget is not None else None
-    engage = (budget is not None and prune
-              and bool(budget.config_constraints()))
-    pruner = TwoStagePruner(budget, chunk_size, cost_model, stats,
-                            telemetry=telemetry) \
-        if engage else None
+    with tr.span("walk_setup"):
+        cost_model = as_cost_model(surrogate)
+        acc_matrix = accuracy_matrix(models, accuracy)
+        walk = plan_joint_walk(models, space=space, chunk_size=chunk_size,
+                               max_points=max_points, seed=seed,
+                               mix_models=mix_models,
+                               layer_buckets=layer_buckets)
+        archive = ParetoArchive(len(COEXPLORE_METRICS))
+        per_model_best: dict[tuple[str, str], dict] = {}
+        stats = BudgetStats() if budget is not None else None
+        engage = (budget is not None and prune
+                  and bool(budget.config_constraints()))
+        pruner = TwoStagePruner(budget, chunk_size, cost_model, stats,
+                                telemetry=telemetry) \
+            if engage else None
     total = 0
 
     def _fold_chunk(res, idx, mids, codes):
@@ -486,25 +519,17 @@ def coexplore_front(
         Shared by both walks, so the constrained mixed walk stays
         bit-identical to the constrained per-model oracle walk for the
         same reason the unconstrained ones match: identical host-side
-        arithmetic on identical device sums, and row masking commutes
-        with both the archive reduction and the best-seen aggregates.
+        arithmetic on identical device sums.
         """
         nonlocal total
-        lane_acc = acc_matrix[mids, codes]
-        obj = _joint_objectives(res, lane_acc)
         total += len(idx)
-        obj, idx, (mids, codes) = fold_budget_chunk(
-            archive, obj, idx, result=res, budget=budget, accuracy=lane_acc,
-            stats=stats, aux=(mids, codes), telemetry=tr)
-        _update_per_model_best(per_model_best, models, acc_matrix,
-                               mids, codes, obj)
+        _fold_joint(tr, archive, per_model_best, models, acc_matrix, res,
+                    idx, mids, codes, budget=budget, stats=stats)
 
     def _fold_flush(res, idx, aux):
         """One fully-feasible two-stage flush -> archive + aggregates."""
-        obj = _joint_objectives(res, aux["accuracy"])
-        fold_budget_chunk(archive, obj, idx, telemetry=tr)
-        _update_per_model_best(per_model_best, models, acc_matrix,
-                               aux["mids"], aux["codes"], obj)
+        _fold_joint(tr, archive, per_model_best, models, acc_matrix, res,
+                    idx, aux["mids"], aux["codes"], lane_acc=aux["accuracy"])
 
     def _feed(cfg, idx, workload, mids, codes, model_ids=None):
         """Route one raw chunk through the engaged walk (pruned or not)."""
@@ -528,9 +553,9 @@ def coexplore_front(
             for out in pruner.finish():
                 _fold_flush(*out)
 
-    for _, wl, model_ids, mids, cfg, idx in timed_iter(walk.chunks(), tr):
-        _feed(cfg, idx, wl, mids,
-              np.asarray(cfg.pe_type).astype(np.int64), model_ids=model_ids)
+    for _, wl, model_ids, mids, cfg, idx in timed_iter(
+            walk.chunks(telemetry=tr), tr):
+        _feed(cfg, idx, wl, mids, _pe_codes(tr, cfg), model_ids=model_ids)
     _finish_walk()
     return CoexploreFront(archive=archive, models=models, space=space,
                           metrics=COEXPLORE_METRICS,
@@ -582,80 +607,74 @@ def _sharded_coexplore_front(
     """
     from repro.core import shard as _shard
     tr = as_tracer(telemetry)
-    cost_model = as_cost_model(surrogate)
-    acc_matrix = accuracy_matrix(models, accuracy)
-    n_shards, devs = _shard.resolve_shards(shards, devices)
-    depth = _shard.DEFAULT_PIPELINE_DEPTH if pipeline_depth is None \
-        else pipeline_depth
-    engage = (budget is not None and prune
-              and bool(budget.config_constraints()))
-    archives = [ParetoArchive(len(COEXPLORE_METRICS))
-                for _ in range(n_shards)]
-    bests: list[dict] = [{} for _ in range(n_shards)]
-    totals = [0] * n_shards
-    stats = [BudgetStats() for _ in range(n_shards)] \
-        if budget is not None else None
+    with tr.span("walk_setup"):
+        cost_model = as_cost_model(surrogate)
+        acc_matrix = accuracy_matrix(models, accuracy)
+        n_shards, devs = _shard.resolve_shards(shards, devices)
+        depth = _shard.DEFAULT_PIPELINE_DEPTH if pipeline_depth is None \
+            else pipeline_depth
+        engage = (budget is not None and prune
+                  and bool(budget.config_constraints()))
+        archives = [ParetoArchive(len(COEXPLORE_METRICS))
+                    for _ in range(n_shards)]
+        bests: list[dict] = [{} for _ in range(n_shards)]
+        totals = [0] * n_shards
+        stats = [BudgetStats() for _ in range(n_shards)] \
+            if budget is not None else None
 
-    walk = plan_joint_walk(models, space=space, chunk_size=chunk_size,
-                           max_points=max_points, seed=seed,
-                           mix_models=mix_models,
-                           layer_buckets=layer_buckets)
+        walk = plan_joint_walk(models, space=space, chunk_size=chunk_size,
+                               max_points=max_points, seed=seed,
+                               mix_models=mix_models,
+                               layer_buckets=layer_buckets)
 
-    ckpt = None
-    cursor = 0
-    pruner_states = wl_keys = None
-    if checkpoint_dir is not None:
-        ckpt = _shard.SweepCheckpointer(
-            checkpoint_dir, every=checkpoint_every,
-            signature=dict(
-                kind="joint", mix=bool(mix_models), shards=n_shards,
-                chunk_size=int(chunk_size), max_points=max_points,
-                seed=int(seed), metrics=list(COEXPLORE_METRICS),
-                prune=bool(engage),
-                budget=None if budget is None else budget.spec(),
-                space=_shard.space_signature(space),
-                models=[m.name for m in models],
-                workloads=_shard.workloads_signature(models)))
-        loaded = ckpt.load(telemetry=telemetry)
-        if loaded is not None:
-            cursor = int(loaded["cursor"])
-            archives = [ParetoArchive.from_state(a)
-                        for a in loaded["archives"]]
-            bests = [{(m, pe): dict(e) for m, pe, e in shard_best}
-                     for shard_best in loaded["best"]]
-            totals = [int(t) for t in loaded["totals"]]
-            if stats is not None and loaded.get("stats") is not None:
-                stats = [BudgetStats.from_dict(d) for d in loaded["stats"]]
-            pruner_states = loaded.get("pruners")
-            wl_keys = loaded.get("wl_keys")
-    pruners = None
-    if engage:
-        pruners = [TwoStagePruner(budget, chunk_size, cost_model, stats[s],
-                                  telemetry=telemetry, track=f"shard{s}")
-                   for s in range(n_shards)]
-        if pruner_states is not None:
-            for s, (p, st) in enumerate(zip(pruners, pruner_states)):
-                k = wl_keys[s] if wl_keys is not None else None
-                p.restore_state(st, walk.workload_for(k))
-    active_keys: list = list(wl_keys) if wl_keys is not None \
-        else [None] * n_shards
+        ckpt = None
+        cursor = 0
+        pruner_states = wl_keys = None
+        if checkpoint_dir is not None:
+            ckpt = _shard.SweepCheckpointer(
+                checkpoint_dir, every=checkpoint_every,
+                signature=dict(
+                    kind="joint", mix=bool(mix_models), shards=n_shards,
+                    chunk_size=int(chunk_size), max_points=max_points,
+                    seed=int(seed), metrics=list(COEXPLORE_METRICS),
+                    prune=bool(engage),
+                    budget=None if budget is None else budget.spec(),
+                    space=_shard.space_signature(space),
+                    models=[m.name for m in models],
+                    workloads=_shard.workloads_signature(models)))
+            loaded = ckpt.load(telemetry=telemetry)
+            if loaded is not None:
+                cursor = int(loaded["cursor"])
+                archives = [ParetoArchive.from_state(a)
+                            for a in loaded["archives"]]
+                bests = [{(m, pe): dict(e) for m, pe, e in shard_best}
+                         for shard_best in loaded["best"]]
+                totals = [int(t) for t in loaded["totals"]]
+                if stats is not None and loaded.get("stats") is not None:
+                    stats = [BudgetStats.from_dict(d) for d in loaded["stats"]]
+                pruner_states = loaded.get("pruners")
+                wl_keys = loaded.get("wl_keys")
+        pruners = None
+        if engage:
+            pruners = [TwoStagePruner(budget, chunk_size, cost_model, stats[s],
+                                      telemetry=telemetry, track=f"shard{s}")
+                       for s in range(n_shards)]
+            if pruner_states is not None:
+                for s, (p, st) in enumerate(zip(pruners, pruner_states)):
+                    k = wl_keys[s] if wl_keys is not None else None
+                    p.restore_state(st, walk.workload_for(k))
+        active_keys: list = list(wl_keys) if wl_keys is not None \
+            else [None] * n_shards
 
     def _fold(s, res, idx, mids, codes):
-        lane_acc = acc_matrix[mids, codes]
-        obj = _joint_objectives(res, lane_acc)
         totals[s] += len(idx)
-        obj, idx, (mids, codes) = fold_budget_chunk(
-            archives[s], obj, idx, result=res, budget=budget,
-            accuracy=lane_acc, stats=None if stats is None else stats[s],
-            aux=(mids, codes), telemetry=tr)
-        _update_per_model_best(bests[s], models, acc_matrix, mids,
-                               codes, obj)
+        _fold_joint(tr, archives[s], bests[s], models, acc_matrix, res, idx,
+                    mids, codes, budget=budget,
+                    stats=None if stats is None else stats[s])
 
     def _fold_flush(s, res, idx, aux):
-        obj = _joint_objectives(res, aux["accuracy"])
-        fold_budget_chunk(archives[s], obj, idx, telemetry=tr)
-        _update_per_model_best(bests[s], models, acc_matrix,
-                               aux["mids"], aux["codes"], obj)
+        _fold_joint(tr, archives[s], bests[s], models, acc_matrix, res, idx,
+                    aux["mids"], aux["codes"], lane_acc=aux["accuracy"])
 
     def _state() -> dict:
         st = dict(cursor=cursor,
@@ -709,12 +728,12 @@ def _sharded_coexplore_front(
 
     t_disp: dict[int, int] = {}
     for c, (wl_key, wl, model_ids, mids, cfg, idx) in enumerate(
-            timed_iter(walk.chunks(start), tr), start=start):
+            timed_iter(walk.chunks(start, telemetry=tr), tr), start=start):
         if max_chunks is not None and c - start >= max_chunks:
             completed = False
             break
         s = c % n_shards
-        codes = np.asarray(cfg.pe_type).astype(np.int64)
+        codes = _pe_codes(tr, cfg)
         if traced:
             tr.counter("sweep.points", len(idx))
         if engage:

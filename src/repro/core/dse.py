@@ -129,7 +129,10 @@ def _count_ppa_trace() -> None:
 # (mixed-radix chunk decode), ``dispatch`` (jit dispatch of the PPA +
 # dataflow stages), ``device_wait`` (blocking transfer in finish_chunk),
 # ``archive`` (host front reduction), ``checkpoint``, ``prune_stage1`` /
-# ``prune_stage2``.  Compile events piggyback on the trace counters: a
+# ``prune_stage2``; the joint walks add ``walk_setup``, ``objectives``
+# and ``best``.  Nested inside them: ``copy.upload`` (decode's columns to
+# the device), ``copy.fetch`` (device_wait's reads) and
+# ``archive.prefilter``.  Compile events piggyback on the trace counters: a
 # dispatch that bumps trace_count/ppa_trace_count charges its duration to
 # histogram ``compile.L<layers>`` — per-layer-bucket compile attribution.
 
@@ -179,11 +182,12 @@ def _traced_dispatch(tr, cfg, workload, model, pad_to, model_ids=None,
 def _traced_finish(tr, pending: "PendingChunk",
                    track: str | None = None) -> "DseResult":
     """``finish_chunk`` under a ``device_wait`` span (the blocking
-    transfer — in the async pipeline this is where stall time shows)."""
+    transfer — in the async pipeline this is where stall time shows),
+    with its reads nested as ``copy.fetch``."""
     if not tr.enabled:
         return finish_chunk(pending)
     with tr.span("device_wait", track=track):
-        return finish_chunk(pending)
+        return finish_chunk(pending, telemetry=tr)
 
 
 @jax.jit
@@ -221,8 +225,20 @@ def _network_sums(cfg: AcceleratorConfig, clock_ghz: jnp.ndarray,
     return reduce_layer_costs(per_layer, lane_layers.count, barrier=True)
 
 
-def _finish(cost, clock_ghz, area_mm2, leak_mw) -> DseResult:
-    """Network cost sums -> DSE metric columns, on HOST in float64.
+def _fetch(cost, clock_ghz, area_mm2, leak_mw) -> tuple:
+    """The nine device results ``_finish`` reads, copied to host float64
+    one after another in a fixed order."""
+    f64 = lambda x: np.asarray(x, np.float64)  # noqa: E731
+    return (f64(cost.cycles), f64(cost.utilization), f64(cost.macs),
+            f64(cost.energy_mac_pj), f64(cost.energy_mem_pj),
+            f64(cost.energy_dram_pj), f64(clock_ghz), f64(area_mm2),
+            f64(leak_mw))
+
+
+def _finish(cycles, util, macs, e_mac, e_mem, e_dram, clock_ghz,
+            area_mm2, leak_mw) -> DseResult:
+    """Network cost sums (as ``_fetch`` read them) -> DSE metric columns,
+    on HOST in float64.
 
     Deliberately outside jit: the derived arithmetic is a handful of
     elementwise ops per lane, and keeping it in one host implementation
@@ -230,17 +246,12 @@ def _finish(cost, clock_ghz, area_mm2, leak_mw) -> DseResult:
     property that lets a mixed-model bucketed sweep reproduce the
     per-model walk bit-for-bit.
     """
-    f64 = lambda x: np.asarray(x, np.float64)  # noqa: E731
-    cycles, util, macs = f64(cost.cycles), f64(cost.utilization), f64(cost.macs)
-    e_mac, e_mem = f64(cost.energy_mac_pj), f64(cost.energy_mem_pj)
-    e_dram = f64(cost.energy_dram_pj)
-    clock_ghz, area_mm2 = f64(clock_ghz), f64(area_mm2)
     latency_s = cycles / (clock_ghz * 1e9)
     # The paper's energy = synthesized chip power x simulated runtime: the
     # dynamic part is the access-count model (MAC + RF/NoC/gbuf), plus
     # leakage x runtime. DRAM energy is invisible to a DC synthesis flow and
     # is reported separately (energy_total_j).
-    e_chip = (e_mac + e_mem) * 1e-12 + f64(leak_mw) * 1e-3 * latency_s
+    e_chip = (e_mac + e_mem) * 1e-12 + leak_mw * 1e-3 * latency_s
     perf = 1.0 / np.maximum(latency_s, 1e-12)
     return DseResult(
         latency_s=latency_s, energy_j=e_chip,
@@ -402,14 +413,18 @@ def dispatch_chunk(cfg: AcceleratorConfig,
     return PendingChunk(cost, clock, area, leak, n)
 
 
-def finish_chunk(pending: PendingChunk) -> DseResult:
+def finish_chunk(pending: PendingChunk, telemetry=None) -> DseResult:
     """The blocking half of ``evaluate_chunk``: transfer the dispatched
     device arrays and derive the host float64 columns (``_finish`` — the
     same single implementation every path shares, so a pipelined chunk is
-    bit-identical to a synchronous one)."""
+    bit-identical to a synchronous one).  ``telemetry=`` times the
+    transfer (``copy.fetch``: it also waits for the chunk's device work)
+    apart from the arithmetic."""
     if pending.n == 0:
         return _empty_result()
-    res = _finish(pending.cost, pending.clock, pending.area, pending.leak)
+    with as_tracer(telemetry).span("fetch", cat="copy"):
+        cols = _fetch(pending.cost, pending.clock, pending.area, pending.leak)
+    res = _finish(*cols)
     return DseResult(*[np.asarray(col[:pending.n], RESULT_DTYPES[f])
                        for f, col in zip(DseResult._fields, res)])
 
@@ -508,7 +523,7 @@ def fold_budget_chunk(archive, obj, idx, result=None, budget=None,
         obj, idx = obj[mask], idx[mask]
         aux = tuple(a[mask] for a in aux)
     with tr.span("archive", track=track):
-        archive.update(obj, idx)
+        archive.update(obj, idx, telemetry=tr)
     return obj, idx, aux
 
 
@@ -643,8 +658,6 @@ class TwoStagePruner:
             {k: np.asarray(v)[rows] for k, v in aux.items()}
         self._frags.append(frag)
         self._n += kept
-        if self._tr.enabled:
-            self._tr.gauge("prune.buffered", self._n, track=self._track)
         while self._n >= self.chunk_size:
             out = self._flush(self.chunk_size)
             if out is not None:
@@ -753,7 +766,7 @@ class TwoStagePruner:
         with self._tr.span("prune_stage2", track=self._track):
             cost = _network_stage(cfg, jnp.asarray(clock), self._workload,
                                   None if mids is None else jnp.asarray(mids))
-            full = _finish(cost, clock, area, leak)
+            full = _finish(*_fetch(cost, clock, area, leak))
         _note_compiles(self._tr, mark, t0, self._workload, track=self._track)
         res = DseResult(*[np.asarray(col[:n], RESULT_DTYPES[f])
                           for f, col in zip(DseResult._fields, full)])
@@ -886,7 +899,8 @@ def evaluate_space_streaming(
                                 telemetry=telemetry)
         for cfg, idx in timed_iter(
                 iter_space_chunks(space, chunk_size=chunk_size,
-                                  max_points=max_points, seed=seed), tr):
+                                  max_points=max_points, seed=seed,
+                                  telemetry=telemetry), tr):
             if tr.enabled:
                 tr.counter("sweep.points", len(idx))
             for res, fidx, _aux in pruner.feed(cfg, idx, workload):
@@ -896,7 +910,8 @@ def evaluate_space_streaming(
         return
     for cfg, idx in timed_iter(
             iter_space_chunks(space, chunk_size=chunk_size,
-                              max_points=max_points, seed=seed), tr):
+                              max_points=max_points, seed=seed,
+                              telemetry=telemetry), tr):
         n_raw = len(idx)
         if tr.enabled:
             tr.counter("sweep.points", n_raw)
@@ -1146,7 +1161,10 @@ class ParetoArchive:
         return mask
 
     def update(self, objectives: np.ndarray,
-               indices: np.ndarray | None = None) -> None:
+               indices: np.ndarray | None = None, telemetry=None) -> None:
+        """Fold a chunk's rows into the front.  ``telemetry=`` times the
+        prefilter against the current front (``archive.prefilter``) and
+        counts the rows that survive it (``archive.survivors``)."""
         obj = np.asarray(objectives, np.float64)
         if obj.ndim != 2 or obj.shape[1] != self._obj.shape[1]:
             raise ValueError(f"expected (N, {self._obj.shape[1]}) objectives, "
@@ -1173,9 +1191,13 @@ class ParetoArchive:
         # keeps the streaming update off the O(N^2) chunk broadcast;
         # stay in host float64 — routing through jnp would downcast to
         # float32 and drop points that differ only past float32 precision
-        if len(self._obj) and len(obj):
-            keep = ~_dominated_by(obj, self._obj)
-            obj, idx = obj[keep], idx[keep]
+        tr = as_tracer(telemetry)
+        with tr.span("prefilter", cat="archive"):
+            if len(self._obj) and len(obj):
+                keep = ~_dominated_by(obj, self._obj)
+                obj, idx = obj[keep], idx[keep]
+        if tr.enabled:
+            tr.counter("archive.survivors", len(obj))
         if len(obj) > 1:
             m = self._chunk_front_mask(obj)
             obj, idx = obj[m], idx[m]

@@ -52,13 +52,11 @@ from repro.core.arch import (joint_space_size, space_points, space_radices,
 from repro.core.constraints import Budget, BudgetStats
 from repro.core.costmodel import CostModel, as_cost_model
 from repro.core.coexplore import (COEXPLORE_METRICS, CoexploreFront,
-                                  ModelEntry, _joint_objectives,
-                                  _update_per_model_best, accuracy_matrix,
-                                  plan_joint_walk)
+                                  ModelEntry, _fold_joint, _pe_codes,
+                                  accuracy_matrix, plan_joint_walk)
 from repro.core.dse import (DEFAULT_CHUNK_SIZE, ParetoArchive, _PPAView,
                             _pad_config, _ppa_stage, _traced_dispatch,
-                            _traced_finish, dispatch_chunk, finish_chunk,
-                            fold_budget_chunk)
+                            _traced_finish, dispatch_chunk, finish_chunk)
 from repro.core.ppa import PPAModels
 from repro.obs import as_tracer
 
@@ -461,8 +459,6 @@ def _make_screen(models, space, cost_model, acc_matrix, budget, chunk_size,
             return ScreenResult(np.empty((0,), bool),
                                 np.empty((0, 3), np.float64), empty)
         counters["screened"] += len(idx)
-        if tr.enabled:
-            tr.counter("search.screened", len(idx))
         mids = idx // accel_size
         codes_all, areas, clocks, powers = [], [], [], []
         with tr.span("screen", cat="search"):
@@ -528,7 +524,8 @@ def search_front(
     budget masking + archive folding, and the per-(model, PE) best-seen
     aggregates.  ``max_evals`` caps FULL dataflow evaluations (lanes);
     stage-1 screens (``SuccessiveHalvingDriver``) ride the cheap batched
-    PPA stage and are accounted separately (``search.screened``).
+    PPA stage and are accounted separately (the checkpointed
+    ``screened`` count).
 
     Determinism: proposals are partitioned into per-bucket sub-batches in
     a fixed order, dispatched round-robin over ``shards`` devices with an
@@ -553,59 +550,61 @@ def search_front(
         raise ValueError(f"max_evals must be >= 1, got {max_evals}")
     from repro.core import shard as _shard
     tr = as_tracer(telemetry)
-    driver = search_driver(driver)
-    cost_model = as_cost_model(surrogate)
-    acc_matrix = accuracy_matrix(models, accuracy)
-    walk = plan_joint_walk(models, space=space, chunk_size=chunk_size,
-                           max_points=None, seed=seed, mix_models=True,
-                           layer_buckets=layer_buckets)
-    accel = space_size(space)
-    total_points = joint_space_size(space, len(models))
-    n_shards, devs = _shard.resolve_shards(shards, devices)
-    depth = _shard.DEFAULT_PIPELINE_DEPTH if pipeline_depth is None \
-        else pipeline_depth
-    counters = {"screened": 0}
-    ctx = SearchContext(
-        space=space, num_models=len(models), accel_size=accel,
-        total_points=total_points, max_evals=int(max_evals), seed=int(seed),
-        acc_matrix=acc_matrix,
-        screen=_make_screen(models, space, cost_model, acc_matrix, budget,
-                            chunk_size, accel, telemetry, counters))
-    driver.reset(ctx)
+    with tr.span("setup", cat="search"):
+        driver = search_driver(driver)
+        cost_model = as_cost_model(surrogate)
+        acc_matrix = accuracy_matrix(models, accuracy)
+        walk = plan_joint_walk(models, space=space, chunk_size=chunk_size,
+                               max_points=None, seed=seed, mix_models=True,
+                               layer_buckets=layer_buckets)
+        accel = space_size(space)
+        total_points = joint_space_size(space, len(models))
+        n_shards, devs = _shard.resolve_shards(shards, devices)
+        depth = _shard.DEFAULT_PIPELINE_DEPTH if pipeline_depth is None \
+            else pipeline_depth
+        counters = {"screened": 0}
+        ctx = SearchContext(
+            space=space, num_models=len(models), accel_size=accel,
+            total_points=total_points, max_evals=int(max_evals),
+            seed=int(seed), acc_matrix=acc_matrix,
+            screen=_make_screen(models, space, cost_model, acc_matrix,
+                                budget, chunk_size, accel, telemetry,
+                                counters))
+        driver.reset(ctx)
 
-    archive = ParetoArchive(len(COEXPLORE_METRICS))
-    per_model_best: dict = {}
-    stats = BudgetStats() if budget is not None else None
-    evals = 0
-    generation = 0
+        archive = ParetoArchive(len(COEXPLORE_METRICS))
+        per_model_best: dict = {}
+        stats = BudgetStats() if budget is not None else None
+        evals = 0
+        generation = 0
 
-    ckpt = None
-    if checkpoint_dir is not None:
-        ckpt = _shard.SweepCheckpointer(
-            checkpoint_dir, every=max(1, int(checkpoint_every)),
-            # max_evals intentionally NOT in the signature: resuming an
-            # interrupted run with a larger budget is the point of
-            # durability, and the driver state makes it exact
-            signature=dict(
-                kind="search", driver=driver.name, shards=n_shards,
-                chunk_size=int(chunk_size),
-                seed=int(seed), metrics=list(COEXPLORE_METRICS),
-                budget=None if budget is None else budget.spec(),
-                space=_shard.space_signature(space),
-                models=[m.name for m in models],
-                workloads=_shard.workloads_signature(models),
-                backend=cost_model.name))
-        loaded = ckpt.load(telemetry=telemetry)
-        if loaded is not None:
-            archive = ParetoArchive.from_state(loaded["archive"])
-            per_model_best = {(m, pe): dict(e)
-                              for m, pe, e in loaded["best"]}
-            evals = int(loaded["evals"])
-            generation = int(loaded["cursor"])
-            counters["screened"] = int(loaded["screened"])
-            if stats is not None and loaded.get("stats") is not None:
-                stats = BudgetStats.from_dict(loaded["stats"])
-            driver.restore_state(loaded["driver"])
+        ckpt = None
+        if checkpoint_dir is not None:
+            ckpt = _shard.SweepCheckpointer(
+                checkpoint_dir, every=max(1, int(checkpoint_every)),
+                # max_evals intentionally NOT in the signature: resuming
+                # an interrupted run with a larger budget is the point of
+                # durability, and the driver state makes it exact
+                signature=dict(
+                    kind="search", driver=driver.name, shards=n_shards,
+                    chunk_size=int(chunk_size),
+                    seed=int(seed), metrics=list(COEXPLORE_METRICS),
+                    budget=None if budget is None else budget.spec(),
+                    space=_shard.space_signature(space),
+                    models=[m.name for m in models],
+                    workloads=_shard.workloads_signature(models),
+                    backend=cost_model.name))
+            loaded = ckpt.load(telemetry=telemetry)
+            if loaded is not None:
+                archive = ParetoArchive.from_state(loaded["archive"])
+                per_model_best = {(m, pe): dict(e)
+                                  for m, pe, e in loaded["best"]}
+                evals = int(loaded["evals"])
+                generation = int(loaded["cursor"])
+                counters["screened"] = int(loaded["screened"])
+                if stats is not None and loaded.get("stats") is not None:
+                    stats = BudgetStats.from_dict(loaded["stats"])
+                driver.restore_state(loaded["driver"])
 
     def _state() -> dict:
         st = dict(cursor=generation, archive=archive.state_dict(),
@@ -618,14 +617,11 @@ def search_front(
         return st
 
     def _fold(res, idx, mids, codes):
-        lane_acc = acc_matrix[mids, codes]
-        obj = _joint_objectives(res, lane_acc)
-        m_obj, m_idx, (m_mids, m_codes) = fold_budget_chunk(
-            archive, obj, idx, result=res, budget=budget, accuracy=lane_acc,
-            stats=stats, aux=(mids, codes), telemetry=tr, track="search")
-        _update_per_model_best(per_model_best, models, acc_matrix,
-                               m_mids, m_codes, m_obj)
-        driver.observe(idx, obj, np.isin(idx, m_idx, assume_unique=True))
+        obj, m_idx = _fold_joint(tr, archive, per_model_best, models,
+                                 acc_matrix, res, idx, mids, codes,
+                                 budget=budget, stats=stats, track="search")
+        with tr.span("observe", cat="search"):
+            driver.observe(idx, obj, np.isin(idx, m_idx, assume_unique=True))
 
     traced = tr.enabled
     cap = max(1, n_shards * max(1, depth))
@@ -640,12 +636,18 @@ def search_front(
         generation += 1
         if traced:
             tr.counter("search.generations")
-            tr.counter("search.proposed", len(proposed))
         # partition into per-bucket sub-batches (fixed bucket order), cut
         # to the compiled chunk shape, dispatch round-robin over devices,
         # finish OLDEST-FIRST: fold order == dispatch order == a pure
         # function of the proposal order, shard-count invariant
-        mids_all = proposed // accel
+        with tr.span("partition", cat="search"):
+            mids_all = proposed // accel
+            parts = []
+            for group in walk.group_ids:
+                sel = np.isin(mids_all, np.asarray(group, np.int64))
+                if sel.any():
+                    b = walk.bucket_of[int(mids_all[sel][0])]
+                    parts.append((walk.stacked[b], proposed[sel]))
         inflight: deque = deque()
         c = 0
 
@@ -655,23 +657,16 @@ def search_front(
             res = _traced_finish(tr, pending, track="search") if traced \
                 else finish_chunk(pending)
             evals += len(idx)
-            if traced:
-                tr.counter("search.evals", len(idx))
             _fold(res, idx, mids, codes)
 
-        for group in walk.group_ids:
-            sel = np.isin(mids_all, np.asarray(group, np.int64))
-            if not sel.any():
-                continue
-            g_idx = proposed[sel]
-            b = walk.bucket_of[int(mids_all[sel][0])]
-            stacked = walk.stacked[b]
+        for stacked, g_idx in parts:
             for lo in range(0, len(g_idx), chunk_size):
                 idx = g_idx[lo:lo + chunk_size]
-                mids = idx // accel
-                cfg = space_points(idx % accel, space)
-                codes = np.asarray(cfg.pe_type).astype(np.int64)
-                model_ids = walk.local[mids]
+                with tr.span("decode", cat="search"):
+                    mids = idx // accel
+                    cfg = space_points(idx % accel, space, tr)
+                    codes = _pe_codes(tr, cfg)
+                    model_ids = walk.local[mids]
                 with jax.default_device(
                         _shard.shard_device(devs, c % n_shards)):
                     pending = _traced_dispatch(

@@ -18,6 +18,7 @@ from repro.obs.tracer import (
     MAX_EVENTS,
     MAX_SAMPLES,
     RSS_INTERVAL_S,
+    TOP_LEVEL_COUNTER,
     Counter,
     Gauge,
     Histogram,
@@ -43,6 +44,7 @@ __all__ = [
     "MAX_EVENTS",
     "MAX_SAMPLES",
     "RSS_INTERVAL_S",
+    "TOP_LEVEL_COUNTER",
     "Counter",
     "Gauge",
     "Histogram",

@@ -39,9 +39,10 @@ def _track_order(tracks) -> list[str]:
 def chrome_trace(tracer, process_name: str = "sweep") -> dict:
     """The tracer's event buffer as a Chrome trace-event JSON object
     (``{"traceEvents": [...]}``) — load it in chrome://tracing or
-    Perfetto.  Spans/completes become "X" events, instants "i", gauge
-    samples "C" counter tracks; one lane per distinct event track with
-    the host (``main``) lane sorted first."""
+    Perfetto.  Spans/completes become "X" events (a nested span's
+    parent in its ``args``), instants "i", gauge samples "C" counter
+    tracks; one lane per distinct event track with the host (``main``)
+    lane sorted first."""
     events = tracer.events
     t0 = tracer.t0_ns
     tracks = {e.track or _MAIN_TRACK for e in events}
@@ -68,8 +69,10 @@ def chrome_trace(tracer, process_name: str = "sweep") -> dict:
         else:
             ev = {"ph": "i", "name": e.name, "cat": e.cat, "pid": 0,
                   "tid": tid, "ts": ts_us, "s": "t"}
-        if e.args:
-            ev["args"] = dict(e.args)
+        if e.args or e.parent is not None:
+            ev["args"] = dict(e.args or {})
+            if e.parent is not None:
+                ev["args"]["parent"] = e.parent
         out.append(ev)
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 

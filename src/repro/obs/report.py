@@ -7,10 +7,13 @@ benchmarks and ROADMAP actually ask:
 * **wall-clock attribution** — where did the time go, as seconds and a
   share of wall, across the host-side phases (``sweep.decode``,
   ``sweep.dispatch``, ``sweep.device_wait``, ``sweep.archive``,
-  ``sweep.checkpoint``, pruner stages...).  The host loop is sequential,
-  so the shares should sum to ~100% of wall — ``coverage`` says how much
-  of wall the instrumented phases account for, and a low value means a
-  hot path is missing a span, not that the report is wrong.
+  ``sweep.checkpoint``, pruner stages...).  ``coverage`` says how much
+  of wall the program's top-level spans account for (counter
+  ``trace.top_level_s``: spans of any category that no other span
+  encloses, so nested spans are not counted twice); a low value means a
+  hot path is missing a span, not that the report is wrong.  A tracer
+  with no spans (only ``complete`` events) falls back to the sum of the
+  ``sweep.*`` phases.
 * **throughput over time** — the ``sweep.points`` counter series binned
   into a pts/s timeline (warm-up cliffs and checkpoint stalls show up as
   dips), plus overall pts/s.
@@ -29,6 +32,8 @@ import math
 import os
 from dataclasses import dataclass, field
 
+from repro.obs.tracer import TOP_LEVEL_COUNTER
+
 # Registry names the instrumented walks use (keep in sync with dse/shard/
 # coexplore/serve instrumentation; tests import these).
 POINTS_COUNTER = "sweep.points"
@@ -46,7 +51,7 @@ class SweepReport:
     points: float
     pts_per_s: float
     attribution: dict = field(default_factory=dict)   # phase -> {seconds, share, count}
-    coverage: float = 0.0                             # accounted / wall
+    coverage: float = 0.0                             # top-level spans / wall
     compiles: dict = field(default_factory=dict)      # bucket -> {count, seconds}
     n_compiles: int = 0
     rss: dict = field(default_factory=dict)
@@ -118,6 +123,8 @@ def build_sweep_report(tracer, wall_s: float | None = None,
                                   count=h.count, p50=h.quantile(0.5),
                                   p99=h.quantile(0.99))
         accounted += h.total
+    if TOP_LEVEL_COUNTER in counters:
+        accounted = counters[TOP_LEVEL_COUNTER].value
     coverage = (accounted / wall_s) if wall_s and math.isfinite(wall_s) else float("nan")
 
     # -- compile attribution per layer bucket ----------------------------

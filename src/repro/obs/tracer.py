@@ -17,6 +17,13 @@ is the instrumentation substrate every hot path threads through:
   ``<cat>.<name>`` AND as a trace event), ``instant``/``complete`` emit
   point/retroactive events, ``counter``/``gauge``/``observe`` feed the
   registry, and a periodic RSS sampler rides along on span exits.
+  Spans nest: each thread keeps a stack of its open spans, every span's
+  event names its parent (``<cat>.<name>`` of the enclosing span), and a
+  span that closes with no parent adds its seconds to counter
+  ``trace.top_level_s`` — so a window's length minus that counter is the
+  host time no span explains (coverage).  ``annotate=True`` also opens
+  each span as a ``jax.profiler.TraceAnnotation`` named
+  ``<cat>.<name>``, putting the program's spans on the profiler's clock.
   Timestamps are ``time.perf_counter_ns()`` — monotonic, so span
   durations and the Chrome trace are immune to wall-clock steps.  Events
   carry a ``track`` (one lane per shard in the Chrome trace; see
@@ -58,6 +65,8 @@ MAX_SAMPLES = 65536
 MAX_EVENTS = 1_000_000
 # Default seconds between periodic RSS gauge samples.
 RSS_INTERVAL_S = 0.25
+# Counter of the seconds spent in spans that have no parent span.
+TOP_LEVEL_COUNTER = "trace.top_level_s"
 
 _PAGE_SIZE = os.sysconf("SC_PAGE_SIZE") if hasattr(os, "sysconf") else 4096
 
@@ -285,11 +294,14 @@ class MetricsRegistry:
 
 class TraceEvent:
     """One recorded event: ``ph`` follows the Chrome trace-event phase
-    alphabet ("X" complete, "i" instant, "C" counter sample)."""
+    alphabet ("X" complete, "i" instant, "C" counter sample).  ``parent``
+    is the ``<cat>.<name>`` of the span that enclosed a span, or None."""
 
-    __slots__ = ("ph", "name", "cat", "ts_ns", "dur_ns", "track", "args")
+    __slots__ = ("ph", "name", "cat", "ts_ns", "dur_ns", "track", "args",
+                 "parent")
 
-    def __init__(self, ph, name, cat, ts_ns, dur_ns, track, args):
+    def __init__(self, ph, name, cat, ts_ns, dur_ns, track, args,
+                 parent=None):
         self.ph = ph
         self.name = name
         self.cat = cat
@@ -297,23 +309,29 @@ class TraceEvent:
         self.dur_ns = dur_ns
         self.track = track
         self.args = args
+        self.parent = parent
 
     def as_dict(self) -> dict:
         d = dict(ph=self.ph, name=self.name, cat=self.cat,
                  ts_ns=self.ts_ns, track=self.track)
         if self.dur_ns is not None:
             d["dur_ns"] = self.dur_ns
+        if self.parent is not None:
+            d["parent"] = self.parent
         if self.args:
             d["args"] = self.args
         return d
 
 
 class _Span:
-    """Context manager recording one timed phase.  On exit the duration
-    lands in histogram ``<cat>.<name>`` (seconds) and — when the tracer
-    records events — as one complete ("X") trace event."""
+    """Context manager recording one timed phase.  On entry it goes on
+    its thread's stack of open spans (the span below it is its parent);
+    on exit the duration lands in histogram ``<cat>.<name>`` (seconds),
+    in counter ``trace.top_level_s`` when it has no parent, and — when
+    the tracer records events — as one complete ("X") trace event."""
 
-    __slots__ = ("_tr", "name", "cat", "track", "args", "t0")
+    __slots__ = ("_tr", "name", "cat", "track", "args", "t0", "parent",
+                 "_ann")
 
     def __init__(self, tr, name, cat, track, args):
         self._tr = tr
@@ -322,8 +340,17 @@ class _Span:
         self.track = track
         self.args = args
         self.t0 = 0
+        self.parent = None
+        self._ann = None
 
     def __enter__(self):
+        tr = self._tr
+        stack = tr._open_spans()
+        self.parent = stack[-1] if stack else None
+        stack.append(self)
+        if tr._annotation is not None:
+            self._ann = tr._annotation(f"{self.cat}.{self.name}")
+            self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
@@ -400,8 +427,12 @@ class Tracer:
     ``record_events=False`` keeps only the registry aggregates (cheapest
     enabled mode — what the benchmark harness uses when no telemetry dir
     is configured).  ``rss_interval_s`` controls the periodic RSS gauge
-    (samples ride along on span exits; 0 disables).  Thread-safe: spans
-    may be entered/exited concurrently from the serving engine's threads.
+    (samples ride along on span exits; 0 disables).  ``annotate=True``
+    also opens every span as a ``jax.profiler.TraceAnnotation``
+    ``<cat>.<name>``, so a ``jax.profiler`` capture shows the program's
+    spans beside the device's work (jax is imported only then).
+    Thread-safe: spans may be entered/exited concurrently from the
+    serving engine's threads; each thread nests its own spans.
     """
 
     enabled = True
@@ -409,13 +440,19 @@ class Tracer:
     def __init__(self, registry: MetricsRegistry | None = None,
                  jsonl_path: str | None = None,
                  record_events: bool = True,
-                 rss_interval_s: float = RSS_INTERVAL_S):
+                 rss_interval_s: float = RSS_INTERVAL_S,
+                 annotate: bool = False):
         self.registry = registry if registry is not None else MetricsRegistry()
         self.t0_ns = time.perf_counter_ns()
         self.dropped_events = 0
         self._events: list[TraceEvent] = []
         self._record_events = record_events
         self._lock = threading.Lock()
+        self._local = threading.local()
+        self._annotation = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self._rss_interval = float(rss_interval_s)
         self._last_rss = 0.0
         self._jsonl_path = jsonl_path
@@ -451,12 +488,32 @@ class Tracer:
              **args) -> _Span:
         return _Span(self, name, cat, track, args or None)
 
+    def _open_spans(self) -> list:
+        """This thread's stack of open spans, innermost last."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
+
     def _end_span(self, span: _Span) -> None:
         end = time.perf_counter_ns()
         dur = end - span.t0
+        if span._ann is not None:
+            span._ann.__exit__(None, None, None)
+        stack = self._open_spans()
+        if stack and stack[-1] is span:
+            stack.pop()
+        elif span in stack:      # closed out of order: drop it where it is
+            stack.remove(span)
         self.registry.histogram(f"{span.cat}.{span.name}").observe(dur / 1e9)
-        self._emit(TraceEvent("X", span.name, span.cat, span.t0, dur,
-                              span.track, span.args))
+        parent = span.parent
+        if parent is None:
+            with self._lock:
+                self.registry.counter(TOP_LEVEL_COUNTER).inc(dur / 1e9)
+        self._emit(TraceEvent(
+            "X", span.name, span.cat, span.t0, dur, span.track, span.args,
+            None if parent is None else f"{parent.cat}.{parent.name}"))
         self.sample_rss()
 
     def instant(self, name: str, cat: str = "sweep",

@@ -4,24 +4,40 @@ instrumentation contract — fronts bit-identical with telemetry on or off
 near-zero disabled cost, one Chrome-trace lane per shard, checkpoint and
 serving events, and the registry-derived benchmark helpers."""
 
+import glob
 import json
+import math
+import os
+import sys
 import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+try:
+    from hypothesis import given, settings, strategies as st
+except ImportError:  # CI images without hypothesis: deterministic fallback
+    from _hypothesis_fallback import given, settings, st
+
 import repro.obs.tracer as tracer_mod
 from repro.checkpoint import manager
-from repro.core import (Budget, coexplore_front, enumerate_space,
-                        evaluate_space_streaming, fit_ppa_models,
-                        model_entry, pareto_front_streaming, resnet_cifar,
+from repro.core import (Budget, ParetoArchive, coexplore_front,
+                        dispatch_chunk, enumerate_space,
+                        evaluate_space_streaming, finish_chunk,
+                        fit_ppa_models, model_entry, pareto_front_streaming,
+                        resnet_cifar, space_points, space_size,
                         transformer_gemm)
-from repro.obs import (MAX_SAMPLES, Histogram, MetricsRegistry, NULL_TRACER,
-                       NullTracer, Tracer, as_tracer, build_sweep_report,
-                       chrome_trace, load_sweep_report, rss_mb, timed_iter,
-                       trace_lanes, write_chrome_trace, write_sweep_report)
+from repro.core.coexplore import plan_joint_walk
+from repro.core.dse import _dominated_by
+from repro.core.search import EvolutionaryDriver, search_front
+from repro.obs import (MAX_SAMPLES, TOP_LEVEL_COUNTER, Histogram,
+                       MetricsRegistry, NULL_TRACER, NullTracer, Tracer,
+                       as_tracer, build_sweep_report, chrome_trace,
+                       load_sweep_report, rss_mb, timed_iter, trace_lanes,
+                       write_chrome_trace, write_sweep_report)
 
 TINY_SPACE = dict(
     pe_rows=(8, 12), pe_cols=(8, 14), gbuf_kb=(54.0,), spad_ifmap=(12,),
@@ -482,3 +498,388 @@ class TestBenchCommon:
         assert t.seconds >= 0.01
         assert REGISTRY.histogram("bench.obs_sweep").count == before + 1
         assert rss_growth_mb(mark) >= 0.0
+
+
+# ---------------------------------------------------------------------------
+# span nesting, top-level accounting, profiler annotations
+# ---------------------------------------------------------------------------
+
+def _parents(tr) -> dict:
+    return {f"{e.cat}.{e.name}": e.parent for e in tr.events if e.ph == "X"}
+
+
+class TestNesting:
+
+    def test_nested_spans_name_parent_and_count_top_level(self, tmp_path):
+        path = str(tmp_path / "events.jsonl")
+        with Tracer(jsonl_path=path, rss_interval_s=0) as tr:
+            with tr.span("walk_setup"):
+                with tr.span("decode"):
+                    with tr.span("upload", cat="copy"):
+                        pass
+                with tr.span("prefilter", cat="archive"):
+                    pass
+            with tr.span("archive"):
+                pass
+        want = {"sweep.walk_setup": None, "sweep.decode": "sweep.walk_setup",
+                "copy.upload": "sweep.decode",
+                "archive.prefilter": "sweep.walk_setup",
+                "sweep.archive": None}
+        assert _parents(tr) == want
+        h = tr.registry.histograms
+        top = tr.registry.counters[TOP_LEVEL_COUNTER]
+        assert top.count == 2                    # only the parentless two
+        assert top.value == pytest.approx(
+            h["sweep.walk_setup"].total + h["sweep.archive"].total)
+        assert top.value < sum(x.total for x in h.values())
+        with open(path) as f:
+            lines = [json.loads(ln) for ln in f]
+        assert {f"{d['cat']}.{d['name']}": d.get("parent")
+                for d in lines if d["ph"] == "X"} == want
+        chrome = {f"{e['cat']}.{e['name']}": e.get("args", {}).get("parent")
+                  for e in chrome_trace(tr)["traceEvents"] if e["ph"] == "X"}
+        assert chrome == want
+        assert tr._open_spans() == []
+
+    def test_spans_on_two_threads_nest_separately(self):
+        tr = Tracer(rss_interval_s=0)
+        both_open = threading.Barrier(2, timeout=10)
+
+        def work(k):
+            with tr.span(f"outer{k}"):
+                both_open.wait()      # both outer spans open at once
+                with tr.span(f"inner{k}"):
+                    both_open.wait()
+
+        threads = [threading.Thread(target=work, args=(k,)) for k in (0, 1)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=10)
+        assert not any(t.is_alive() for t in threads)
+        assert _parents(tr) == {"sweep.outer0": None, "sweep.outer1": None,
+                                "sweep.inner0": "sweep.outer0",
+                                "sweep.inner1": "sweep.outer1"}
+        h = tr.registry.histograms
+        top = tr.registry.counters[TOP_LEVEL_COUNTER]
+        assert top.count == 2
+        assert top.value == pytest.approx(h["sweep.outer0"].total
+                                          + h["sweep.outer1"].total)
+
+    def test_top_level_counter_loses_no_update_across_threads(self):
+        tr = Tracer(record_events=False, rss_interval_s=0)
+        n_threads, per_thread = 2 * (os.cpu_count() or 2) + 2, 500
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            def work():
+                for _ in range(per_thread):
+                    with tr.span("top"):
+                        with tr.span("nested"):
+                            pass
+            threads = [threading.Thread(target=work)
+                       for _ in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads)
+        top = tr.registry.counters[TOP_LEVEL_COUNTER]
+        assert top.count == n_threads * per_thread
+        assert top.value == pytest.approx(
+            tr.registry.histograms["sweep.top"].total)
+
+    def test_span_closed_out_of_order_leaves_the_stack_clean(self):
+        tr = Tracer(rss_interval_s=0)
+        a, b = tr.span("a"), tr.span("b")
+        a.__enter__()
+        b.__enter__()
+        a.__exit__(None, None, None)
+        b.__exit__(None, None, None)
+        assert tr._open_spans() == []
+        assert _parents(tr) == {"sweep.a": None, "sweep.b": "sweep.a"}
+        with tr.span("c"):
+            pass
+        assert _parents(tr)["sweep.c"] is None
+
+    def test_report_coverage_reads_top_level_seconds(self):
+        tr = Tracer(rss_interval_s=0)
+        with tr.span("decode"):
+            with tr.span("upload", cat="copy"):
+                time.sleep(0.002)
+        with tr.span("propose", cat="search"):
+            time.sleep(0.002)
+        rep = build_sweep_report(tr, wall_s=1.0)
+        top = tr.registry.counters[TOP_LEVEL_COUNTER].value
+        h = tr.registry.histograms
+        assert rep.coverage == pytest.approx(top)
+        # the search span counts, the nested copy does not count twice
+        assert top == pytest.approx(h["sweep.decode"].total
+                                    + h["search.propose"].total)
+
+    def test_annotate_puts_spans_in_the_profiler_trace(self, tmp_path):
+        annotated = Tracer(annotate=True, rss_interval_s=0)
+        plain = Tracer(rss_interval_s=0)
+        with jax.profiler.trace(str(tmp_path)):
+            with annotated.span("decode"):
+                with annotated.span("upload", cat="copy"):
+                    jnp.ones(8).block_until_ready()
+            with plain.span("unannotated"):
+                pass
+        files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        assert files
+        data = jax.profiler.ProfileData.from_file(files[-1])
+        names = {e.name for plane in data.planes for line in plane.lines
+                 for e in line.events}
+        assert {"sweep.decode", "copy.upload"} <= names
+        assert "sweep.unannotated" not in names
+        assert _parents(annotated) == {"sweep.decode": None,
+                                       "copy.upload": "sweep.decode"}
+
+
+# ---------------------------------------------------------------------------
+# the chunk path's spans and counters, per chunk and per walk or search
+# ---------------------------------------------------------------------------
+
+class _Recording(EvolutionaryDriver):
+    """Keeps every evaluated (indices, objectives) the search observes."""
+
+    def reset(self, ctx):
+        super().reset(ctx)
+        self.seen = []
+
+    def observe(self, idx, obj, feasible):
+        self.seen.append((np.array(idx), np.array(obj)))
+        super().observe(idx, obj, feasible)
+
+
+def _search(models, telemetry=None, seed=1):
+    drv = _Recording(population=32)
+    front = search_front(models, space=TINY_SPACE, driver=drv, max_evals=96,
+                         seed=seed, chunk_size=CHUNK, telemetry=telemetry)
+    return front, drv.seen
+
+
+PER_CHUNK = ("copy.upload", "copy.codes", "copy.fetch", "sweep.dispatch",
+             "sweep.objectives", "sweep.archive", "archive.prefilter",
+             "sweep.best")
+
+
+class TestChunkPathSpans:
+
+    def test_walk_records_every_span_per_chunk(self, tiny_models):
+        kw = dict(chunk_size=CHUNK, max_points=150, seed=3)
+        tr = Tracer(rss_interval_s=0)
+        t0 = time.perf_counter()
+        fronts = [coexplore_front(tiny_models, TINY_SPACE, telemetry=tr,
+                                  **kw) for _ in range(2)]
+        wall = time.perf_counter() - t0
+        h, c = tr.registry.histograms, tr.registry.counters
+        chunks = h["sweep.device_wait"].count
+        plan = plan_joint_walk(tiny_models, TINY_SPACE, **kw)
+        assert chunks == 2 * sum(1 for _ in plan.chunks())
+        for name in PER_CHUNK:
+            assert h[name].count == chunks, name
+        assert h["sweep.decode"].count == chunks + 2  # + each StopIteration
+        assert h["sweep.walk_setup"].count == 2       # one per walk
+        surv = c["archive.survivors"]
+        assert surv.count == chunks
+        assert 0 < surv.value <= 2 * fronts[0].points_evaluated
+        assert _parents(tr) == {
+            "sweep.walk_setup": None, "sweep.decode": None,
+            "copy.upload": "sweep.decode", "copy.codes": None,
+            "sweep.dispatch": None, "sweep.device_wait": None,
+            "copy.fetch": "sweep.device_wait", "sweep.objectives": None,
+            "sweep.archive": None, "archive.prefilter": "sweep.archive",
+            "sweep.best": None}
+        top = c[TOP_LEVEL_COUNTER].value
+        assert 0.5 * wall < top <= wall
+
+    def test_search_records_every_span_per_chunk(self, tiny_models):
+        tr = Tracer(rss_interval_s=0)
+        front, _ = _search(tiny_models, tr)
+        h, c = tr.registry.histograms, tr.registry.counters
+        chunks = h["sweep.device_wait"].count
+        assert chunks >= 2
+        for name in PER_CHUNK + ("search.decode", "search.observe"):
+            assert h[name].count == chunks, name
+        assert h["search.setup"].count == 1
+        assert h["search.partition"].count == c["search.generations"].value
+        assert c["archive.survivors"].count == chunks
+        parents = _parents(tr)
+        assert parents["copy.upload"] == "search.decode"
+        assert parents["copy.codes"] == "search.decode"
+        assert parents["copy.fetch"] == "sweep.device_wait"
+        assert parents["archive.prefilter"] == "sweep.archive"
+        for name in ("search.setup", "search.propose", "search.partition",
+                     "search.decode", "search.observe", "sweep.objectives",
+                     "sweep.best"):
+            assert parents[name] is None, name
+        # the counters that repeated other numbers are gone
+        for name in ("search.screened", "search.proposed", "search.evals"):
+            assert name not in c
+
+    def test_pruned_walk_keeps_no_buffered_gauge(self, tiny_models):
+        tr = Tracer(rss_interval_s=0)
+        coexplore_front(tiny_models, TINY_SPACE, chunk_size=CHUNK,
+                        budget=Budget(area_mm2=0.6), prune=True,
+                        telemetry=tr)
+        assert tr.registry.counters["prune.flushes"].value >= 1
+        assert "prune.buffered" not in tr.registry.gauges
+        assert tr.registry.histograms["sweep.walk_setup"].count == 1
+
+
+# ---------------------------------------------------------------------------
+# the new telemetry= paths leave every value bit-identical
+# ---------------------------------------------------------------------------
+
+def _assert_configs_equal(a, b):
+    for f in a._fields:
+        x, y = np.asarray(getattr(a, f)), np.asarray(getattr(b, f))
+        assert x.dtype == y.dtype, f
+        np.testing.assert_array_equal(x, y, err_msg=f)
+
+
+class TestTelemetryPathsBitIdentical:
+
+    @given(seed=st.integers(0, 2**31 - 1), n=st.integers(1, 40),
+           annotate=st.booleans())
+    @settings(max_examples=10, deadline=None)
+    def test_space_points(self, seed, n, annotate):
+        idx = np.random.default_rng(seed).integers(
+            0, space_size(TINY_SPACE), size=n)
+        tr = Tracer(rss_interval_s=0, annotate=annotate)
+        _assert_configs_equal(space_points(idx, TINY_SPACE),
+                              space_points(idx, TINY_SPACE, telemetry=tr))
+        assert tr.registry.histograms["copy.upload"].count == 1
+
+    @pytest.mark.parametrize("annotate", (False, True))
+    def test_finish_chunk(self, workload, annotate):
+        cfg = space_points(np.arange(CHUNK - 3), TINY_SPACE)
+        pending = dispatch_chunk(cfg, workload, pad_to=CHUNK)
+        tr = Tracer(rss_interval_s=0, annotate=annotate)
+        plain, traced = finish_chunk(pending), finish_chunk(pending,
+                                                            telemetry=tr)
+        for f in plain._fields:
+            x, y = getattr(plain, f), getattr(traced, f)
+            assert x.dtype == y.dtype and x.shape == (CHUNK - 3,), f
+            np.testing.assert_array_equal(x, y, err_msg=f)
+        assert tr.registry.histograms["copy.fetch"].count == 1
+
+    @given(seed=st.integers(0, 2**31 - 1), chunks=st.integers(1, 6))
+    @settings(max_examples=15, deadline=None)
+    def test_archive_update(self, seed, chunks):
+        rng = np.random.default_rng(seed)
+        plain, traced = ParetoArchive(3), ParetoArchive(3)
+        tr = Tracer(rss_interval_s=0)
+        survivors = 0
+        for k in range(chunks):
+            # small integer grid: ties and duplicates on the front
+            obj = rng.integers(0, 6, size=(int(rng.integers(0, 40)), 3)
+                               ).astype(np.float64)
+            idx = np.arange(k * 40, k * 40 + len(obj))
+            survivors += int((~_dominated_by(obj, plain.objectives)).sum()) \
+                if len(plain) else len(obj)
+            plain.update(obj, idx)
+            traced.update(obj, idx, telemetry=tr)
+            np.testing.assert_array_equal(plain.indices, traced.indices)
+            np.testing.assert_array_equal(plain.objectives,
+                                          traced.objectives)
+        c = tr.registry.counters["archive.survivors"]
+        assert (c.count, c.value) == (chunks, survivors)
+        assert tr.registry.histograms["archive.prefilter"].count == chunks
+
+    @pytest.mark.parametrize("annotate", (False, True))
+    def test_search_front(self, tiny_models, annotate):
+        ref, ref_seen = _search(tiny_models)
+        got, got_seen = _search(tiny_models,
+                                Tracer(rss_interval_s=0, annotate=annotate))
+        _assert_archives_equal(ref.archive, got.archive)
+        assert got.per_model_best == ref.per_model_best
+        assert got.points_evaluated == ref.points_evaluated
+        assert len(got_seen) == len(ref_seen)
+        for (ri, ro), (gi, go) in zip(ref_seen, got_seen):
+            np.testing.assert_array_equal(ri, gi)
+            np.testing.assert_array_equal(ro, go)
+
+    def test_coexplore_front_annotated(self, tiny_models):
+        kw = dict(chunk_size=CHUNK, max_points=150, seed=3)
+        ref = coexplore_front(tiny_models, TINY_SPACE, **kw)
+        got = coexplore_front(tiny_models, TINY_SPACE,
+                              telemetry=Tracer(rss_interval_s=0,
+                                               annotate=True), **kw)
+        _assert_archives_equal(ref.archive, got.archive)
+        assert got.per_model_best == ref.per_model_best
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's per-layer readers of these spans and counters
+# ---------------------------------------------------------------------------
+
+NEW_METRICS = ("unspanned_ms_per_chunk.sweep", "copy_ms_per_chunk.sweep",
+               "archive_prefilter_ms_per_chunk.sweep",
+               "archive_survivors_per_chunk.sweep",
+               "bookkeeping_ms_per_chunk.sweep",
+               "decode_ms_per_chunk.search", "setup_ms_per_walk")
+
+
+def _readings(tracer, window_s):
+    from bench.harness import Readings, Window
+    reduced = dict(window_s=window_s, busy_s={}, exec_s={}, device_ops=[],
+                   idle_gaps=[])
+    return Readings(tracer, reduced,
+                    Window(metrics={}, attempted=1, failed=0))
+
+
+@pytest.fixture(scope="module")
+def traced_cells(tiny_models):
+    """Readings of a traced walk window and a traced search window, as
+    the benchmark's paper_sweep and llm_search cells take them."""
+    out = {}
+    for cell, run in (
+            ("paper_sweep", lambda tr: coexplore_front(
+                tiny_models, TINY_SPACE, chunk_size=CHUNK, telemetry=tr)),
+            ("llm_search", lambda tr: _search(tiny_models, tr))):
+        tr = Tracer(record_events=False, rss_interval_s=0)
+        t0 = time.perf_counter()
+        run(tr)
+        out[cell] = _readings(tr, time.perf_counter() - t0)
+    return out
+
+
+class TestBenchReaders:
+
+    @pytest.mark.parametrize("name", NEW_METRICS)
+    def test_reader_reads_a_traced_window(self, traced_cells, name):
+        from bench import registry
+        spec = registry.load_benchmark(registry.BENCH_DIR.parent)
+        entry = next(m for m in spec["per_layer"] if m["name"] == name)
+        read = registry.metric_reader(name)
+        for cell in ("paper_sweep", "llm_search"):
+            value = read(traced_cells[cell])
+            if cell in entry["workloads"]:
+                assert value is not None and math.isfinite(value), cell
+                assert value >= 0.0, cell
+        # a program without these spans and counters reads nothing
+        assert read(_readings(Tracer(record_events=False,
+                                     rss_interval_s=0), 1.0)) is None
+
+    def test_readers_add_up_against_the_registry(self, traced_cells):
+        from bench import registry
+        r = traced_cells["llm_search"]
+        read = {n: registry.metric_reader(n)(r) for n in NEW_METRICS}
+        per_chunk = lambda *ns: sum(r.span_s(n) for n in ns) \
+            / r.chunks * 1e3  # noqa: E731
+        assert read["copy_ms_per_chunk.sweep"] == pytest.approx(
+            per_chunk("copy.upload", "copy.codes", "copy.fetch"))
+        assert read["archive_survivors_per_chunk.sweep"] == pytest.approx(
+            r.counters["archive.survivors"] / r.chunks)
+        # copies of the search sit inside its decode and finish spans
+        assert read["copy_ms_per_chunk.sweep"] <= per_chunk(
+            "search.decode", "sweep.device_wait")
+        assert read["unspanned_ms_per_chunk.sweep"] == pytest.approx(
+            (r.trace["window_s"] - r.counters[TOP_LEVEL_COUNTER])
+            / r.chunks * 1e3)
