@@ -13,6 +13,7 @@ the idle gaps labelled by the host span open during them.
 from __future__ import annotations
 
 import glob
+import heapq
 import os
 from typing import NamedTuple
 
@@ -190,17 +191,41 @@ def reduce(trace: Trace, executables, top: int = 10) -> dict:
 
 def _label_gaps(host: list, gaps: np.ndarray) -> dict:
     """Idle seconds per label: each gap goes to the host span covering
-    most of it, among equal cover the shortest (innermost) one."""
-    names = [e.name for e in host]
-    starts = np.asarray([e.start_ns for e in host], np.int64)
-    ends = np.asarray([e.end_ns for e in host], np.int64)
+    most of it, among equal cover the shortest (innermost) one, among
+    those the first in ``host``; a gap no span covers is "no host span".
+
+    One sweep over time, O((gaps + spans) log spans): the gaps are taken
+    in order of start, the spans in order of start, and a heap keyed by
+    end holds the spans begun before the gap ends.  A span that ends by
+    a gap's start can cover no later gap and leaves the heap, so each gap
+    looks only at the spans open across it or lying inside it.  Seconds
+    are summed in the caller's order of gaps."""
+    starts = [e.start_ns for e in host]
+    ends = [e.end_ns for e in host]
+    by_start = sorted(range(len(host)), key=starts.__getitem__)
+    labels = ["no host span"] * len(gaps)
+    heap: list[tuple[int, int]] = []      # (end_ns, index in host)
+    nxt = 0
+    pairs = gaps.tolist()
+    for i in np.argsort(gaps[:, 0], kind="stable").tolist():
+        g0, g1 = pairs[i]
+        while nxt < len(by_start) and starts[by_start[nxt]] < g1:
+            k = by_start[nxt]
+            heapq.heappush(heap, (ends[k], k))
+            nxt += 1
+        while heap and heap[0][0] <= g0:
+            heapq.heappop(heap)
+        best = None
+        for end, k in heap:
+            cover = min(end, g1) - max(starts[k], g0)
+            if cover > 0:
+                key = (-cover, end - starts[k], k)
+                if best is None or key < best:
+                    best = key
+        if best is not None:
+            labels[i] = host[best[2]].name
     out: dict[str, float] = {}
-    for g0, g1 in gaps:
-        label = "no host span"
-        if len(host):
-            cover = np.minimum(ends, g1) - np.maximum(starts, g0)
-            if cover.max() > 0:
-                k = np.lexsort((ends - starts, -cover))[0]
-                label = names[k]
-        out[label] = out.get(label, 0.0) + (g1 - g0) / 1e9
+    secs = ((gaps[:, 1] - gaps[:, 0]) / 1e9).tolist()
+    for label, s in zip(labels, secs):
+        out[label] = out.get(label, 0.0) + s
     return out
