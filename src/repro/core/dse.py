@@ -435,7 +435,7 @@ def _empty_result() -> DseResult:
                        for f in DseResult._fields])
 
 
-def chunk_dominators(obj: np.ndarray, block: int = 512):
+def chunk_dominators(obj: np.ndarray):
     """Shared strict-domination structure of one chunk's objective rows:
     the pair ``(front, dom)`` where ``front`` holds the row indices of
     the chunk's own non-dominated front and ``dom[k, r]`` is True when
@@ -455,17 +455,12 @@ def chunk_dominators(obj: np.ndarray, block: int = 512):
     per-query O(N^2) in-chunk reductions become one shared front pass
     plus Q boolean reduces.
 
-    Blocked so the (block, N, D) broadcast temporary stays bounded.
+    ``dom`` is ``_dominance(obj, obj[front]).T``: the same strict
+    relation the archive folds with.
     """
     obj = np.asarray(obj, np.float64)
     front = np.flatnonzero(ParetoArchive._chunk_front_mask(obj))
-    f = obj[front]
-    dom = np.empty((len(front), len(obj)), bool)
-    for lo in range(0, len(front), block):
-        blk = f[lo:lo + block, None, :]
-        dom[lo:lo + block] = (np.all(blk >= obj[None, :, :], axis=-1)
-                              & np.any(blk > obj[None, :, :], axis=-1))
-    return front, dom
+    return front, _dominance(obj, obj[front]).T
 
 
 def fold_budget_chunk(archive, obj, idx, result=None, budget=None,
@@ -1061,22 +1056,51 @@ def pareto_front(result: DseResult,
                        method=method)
 
 
+def _dominance(points: np.ndarray, front: np.ndarray,
+               block: int = 1 << 20) -> np.ndarray:
+    """(N, F) strict-domination matrix: ``[i, j]`` is True when
+    ``front[j]`` dominates ``points[i]`` (>= in every objective, > in at
+    least one, so a row never dominates itself or its duplicate).
+
+    The one host domination primitive.  Built objective by objective from
+    2-D comparisons folded in place into one ``ge``/``gt`` pair — no
+    (N, F, D) temporary, and no reduction over a short trailing axis.
+    Laid out front-major (the result is a transposed view) so every
+    comparison runs along the long points axis; front rows go in blocks
+    of about ``block`` elements so the temporaries stay bounded when
+    ``front`` is large."""
+    n, f = len(points), len(front)
+    if n == 0 or f == 0:
+        return np.zeros((n, f), bool)
+    dom = np.empty((f, n), bool)
+    p_cols = np.ascontiguousarray(points.T)
+    f_cols = front.T[:, :, None]
+    rows = max(1, min(f, block // n))
+    gt, tmp = np.empty((rows, n), bool), np.empty((rows, n), bool)
+    for lo in range(0, f, rows):
+        ge = dom[lo:lo + rows]
+        g, t = gt[:len(ge)], tmp[:len(ge)]
+        (f0, *f_rest), (p0, *p_rest) = f_cols[:, lo:lo + rows], p_cols
+        np.greater_equal(f0, p0, out=ge)
+        np.greater(f0, p0, out=g)
+        for fk, pk in zip(f_rest, p_rest, strict=True):
+            ge &= np.greater_equal(fk, pk, out=t)
+            g |= np.greater(fk, pk, out=t)
+        ge &= g
+    return dom.T
+
+
 def _dominated_by(points: np.ndarray, front: np.ndarray) -> np.ndarray:
     """Boolean mask: is ``points[i]`` dominated by some row of ``front``?
     O(len(points) * len(front) * D) — cheap while ``front`` is small."""
-    if len(front) == 0 or len(points) == 0:
-        return np.zeros(len(points), bool)
-    ge = np.all(front[None, :, :] >= points[:, None, :], axis=-1)
-    gt = np.any(front[None, :, :] > points[:, None, :], axis=-1)
-    return np.any(ge & gt, axis=1)
+    return _dominance(points, front).any(axis=1)
 
 
 def _self_nondominated(pts: np.ndarray) -> np.ndarray:
     """Dense pairwise non-dominated mask of ``pts`` against itself,
-    O(N^2 * D) — reserve for small N (a block of a chunk)."""
-    ge = np.all(pts[None, :, :] >= pts[:, None, :], axis=-1)
-    gt = np.any(pts[None, :, :] > pts[:, None, :], axis=-1)
-    return ~np.any(ge & gt, axis=1)
+    O(N^2 * D) — reserve for small N (a block of a chunk).  Exact: a row
+    never strictly dominates itself or its duplicate."""
+    return ~_dominated_by(pts, pts)
 
 
 class ParetoArchive:
@@ -1137,7 +1161,7 @@ class ParetoArchive:
         the block against the running front — transitivity guarantees an
         *undominated* dominator exists there) or in the same block
         (covered by a dense pass within the block).  Typical cost is
-        O(N log N + N * front * D) — the O(N^2) broadcast only ever
+        O(N log N + N * front * D) — the O(N^2) dense pass only ever
         happens for pathological all-nondominated blocks, and then at
         block granularity.
         """

@@ -289,3 +289,128 @@ class TestReportPeTypes:
             assert {"best_perf_per_area", "norm_perf_per_area",
                     "best_energy_j", "norm_energy",
                     "energy_at_best_ppa"} <= set(r)
+
+
+# ---------------------------------------------------------------------------
+# The host domination primitive against the (N, F, D) broadcast it replaced.
+# These oracles are the earlier production formulas, kept verbatim.
+
+def _oracle_dominated_by(points: np.ndarray, front: np.ndarray) -> np.ndarray:
+    if len(front) == 0 or len(points) == 0:
+        return np.zeros(len(points), bool)
+    ge = np.all(front[None, :, :] >= points[:, None, :], axis=-1)
+    gt = np.any(front[None, :, :] > points[:, None, :], axis=-1)
+    return np.any(ge & gt, axis=1)
+
+
+def _oracle_self_nondominated(pts: np.ndarray) -> np.ndarray:
+    ge = np.all(pts[None, :, :] >= pts[:, None, :], axis=-1)
+    gt = np.any(pts[None, :, :] > pts[:, None, :], axis=-1)
+    return ~np.any(ge & gt, axis=1)
+
+
+def _oracle_chunk_dominators(obj: np.ndarray, block: int = 512):
+    obj = np.asarray(obj, np.float64)
+    front = np.flatnonzero(ParetoArchive._chunk_front_mask(obj))
+    f = obj[front]
+    dom = np.empty((len(front), len(obj)), bool)
+    for lo in range(0, len(front), block):
+        blk = f[lo:lo + block, None, :]
+        dom[lo:lo + block] = (np.all(blk >= obj[None, :, :], axis=-1)
+                              & np.any(blk > obj[None, :, :], axis=-1))
+    return front, dom
+
+
+def _oracle_matrix(points: np.ndarray, front: np.ndarray) -> np.ndarray:
+    ge = np.all(front[None, :, :] >= points[:, None, :], axis=-1)
+    gt = np.any(front[None, :, :] > points[:, None, :], axis=-1)
+    return ge & gt
+
+
+def _grid_rows(rng, n, d):
+    """Integer-grid rows from a tiny alphabet: full of ties and duplicates."""
+    return rng.integers(0, 3, size=(n, d)).astype(np.float64)
+
+
+# (case, rows N, front F, row kind, elements per block of front rows)
+_DOMINANCE_CASES = [
+    ("random", 97, 61, "random", 1 << 20),
+    ("grid", 130, 140, "grid", 1 << 20),
+    ("grid_small_block", 130, 140, "grid", 130 * 16),
+    ("no_rows", 0, 40, "grid", 1 << 20),
+    ("no_front", 40, 0, "grid", 1 << 20),
+    ("one_row", 1, 50, "grid", 1 << 20),
+    ("one_front_row", 50, 1, "grid", 1 << 20),
+    ("front_wider_than_block", 70, 1100, "random", 70 * 64),
+    ("row_wider_than_block", 2100, 9, "grid", 1024),
+    ("points_are_front", 150, None, "grid", 150 * 32),
+    ("points_are_front_random", 150, None, "random", 1 << 20),
+]
+
+
+class TestDominancePrimitive:
+    """``dse._dominance`` and everything built on it must return exactly
+    the booleans of the (N, F, D) broadcast it replaced."""
+
+    @pytest.mark.parametrize("d", [2, 3, 4])
+    @pytest.mark.parametrize("case,n,f,kind,block", _DOMINANCE_CASES,
+                             ids=[c[0] for c in _DOMINANCE_CASES])
+    def test_matches_broadcast_oracle(self, case, n, f, kind, block, d):
+        from repro.core.dse import (_dominance, _dominated_by,
+                                    _self_nondominated)
+        rng = np.random.default_rng([n, f or 0, d, len(case)])
+        rows = _grid_rows if kind == "grid" else (
+            lambda r, m, k: r.random((m, k)))
+        points = rows(rng, n, d)
+        front = points if f is None else rows(rng, f, d)
+        if kind == "grid" and len(points) and len(front):
+            points[:len(points) // 3] = front[
+                rng.integers(0, len(front), len(points) // 3)]
+        want = _oracle_matrix(points, front)
+        got = _dominance(points, front, block=block)
+        assert got.shape == (len(points), len(front)) and got.dtype == bool
+        assert (got == want).all()
+        assert (_dominated_by(points, front)
+                == _oracle_dominated_by(points, front)).all()
+        if f is None:
+            assert (_self_nondominated(points)
+                    == _oracle_self_nondominated(points)).all()
+
+    @pytest.mark.parametrize("d", [2, 3, 4])
+    def test_archive_stream_equals_oracle_archive(self, d, monkeypatch):
+        from repro.core import dse
+        rng = np.random.default_rng(1000 + d)
+        chunks = []
+        for c in range(12):
+            n = int(rng.choice([1, 7, 300, 700]))
+            obj = (_grid_rows(rng, n, d) if c % 2
+                   else np.round(rng.normal(size=(n, d)), 2))
+            chunks.append(obj)
+        new = ParetoArchive(d)
+        for c, obj in enumerate(chunks):
+            new.update(obj, np.arange(c * 1000, c * 1000 + len(obj)))
+        monkeypatch.setattr(dse, "_dominated_by", _oracle_dominated_by)
+        monkeypatch.setattr(dse, "_self_nondominated",
+                            _oracle_self_nondominated)
+        old = ParetoArchive(d)
+        for c, obj in enumerate(chunks):
+            old.update(obj, np.arange(c * 1000, c * 1000 + len(obj)))
+        assert len(new) > 1
+        assert new.objectives.tobytes() == old.objectives.tobytes()
+        assert new.indices.tobytes() == old.indices.tobytes()
+        s_new, s_old = new.state_dict(), old.state_dict()
+        assert s_new.keys() == s_old.keys()
+        for key in s_new:
+            assert (np.asarray(s_new[key]).tobytes()
+                    == np.asarray(s_old[key]).tobytes())
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    @pytest.mark.parametrize("n", [1, 90, 700])
+    def test_chunk_dominators_equal_oracle(self, seed, n):
+        from repro.core import chunk_dominators
+        rng = np.random.default_rng([seed, n])
+        obj = _grid_rows(rng, n, 3) if seed != 1 else rng.random((n, 3))
+        front, dom = chunk_dominators(obj)
+        want_front, want_dom = _oracle_chunk_dominators(obj)
+        assert front.tobytes() == want_front.tobytes()
+        assert dom.shape == want_dom.shape and (dom == want_dom).all()
