@@ -39,6 +39,13 @@ class ArchConfig:
     mrope_sections: Tuple[int, ...] = ()
     query_scale: float = 0.0             # 0 -> 1/sqrt(head_dim)
 
+    # multi-head latent attention (DeepSeek-V2/V3); kv_lora_rank 0 = off
+    q_lora_rank: int = 0                 # query low-rank width
+    kv_lora_rank: int = 0                # width of the cached latent c_kv
+    qk_nope_head_dim: int = 0            # per-head q/k width without rope
+    qk_rope_head_dim: int = 0            # rope width (k_rope shared by heads)
+    v_head_dim: int = 0                  # per-head value width
+
     # MoE
     moe_experts: int = 0
     moe_topk: int = 0
@@ -94,7 +101,7 @@ class ArchConfig:
 ASSIGNED = (
     "qwen3_32b", "gemma3_1b", "gemma2_9b", "smollm_135m", "phi35_moe",
     "deepseek_moe_16b", "rwkv6_1b6", "qwen2_vl_72b", "whisper_medium",
-    "zamba2_7b",
+    "zamba2_7b", "deepseek_v3",
 )
 
 # canonical CLI ids (--arch <id>) -> module names
@@ -109,6 +116,7 @@ ARCH_IDS = {
     "qwen2-vl-72b": "qwen2_vl_72b",
     "whisper-medium": "whisper_medium",
     "zamba2-7b": "zamba2_7b",
+    "deepseek-v3": "deepseek_v3",
 }
 
 

@@ -60,8 +60,8 @@ from repro.core.arch import (AcceleratorConfig, PE_TYPE_NAMES, config_rows,
 from repro.core.constraints import Budget, BudgetStats
 from repro.core.costmodel import CostModel, as_cost_model
 from repro.core.dse import (DEFAULT_CHUNK_SIZE, ParetoArchive, TwoStagePruner,
-                            _traced_dispatch, _traced_finish, dispatch_chunk,
-                            finish_chunk, fold_budget_chunk)
+                            _count_chunk, _traced_dispatch, _traced_finish,
+                            dispatch_chunk, finish_chunk, fold_budget_chunk)
 from repro.obs import as_tracer, timed_iter
 from repro.core.ppa import PPAModels
 from repro.core.workloads import (Workload, acc_class_mix, layer_bucket,
@@ -535,7 +535,7 @@ def coexplore_front(
         """Route one raw chunk through the engaged walk (pruned or not)."""
         nonlocal total
         if tr.enabled:
-            tr.counter("sweep.points", len(idx))
+            _count_chunk(tr, len(idx), workload)
         if not engage:
             pending = _traced_dispatch(tr, cfg, workload, cost_model,
                                        chunk_size, model_ids=model_ids)
@@ -735,7 +735,7 @@ def _sharded_coexplore_front(
         s = c % n_shards
         codes = _pe_codes(tr, cfg)
         if traced:
-            tr.counter("sweep.points", len(idx))
+            _count_chunk(tr, len(idx), wl)
         if engage:
             active_keys[s] = wl_key
             totals[s] += len(idx)

@@ -132,7 +132,10 @@ def _count_ppa_trace() -> None:
 # ``prune_stage2``; the joint walks add ``walk_setup``, ``objectives``
 # and ``best``.  Nested inside them: ``copy.upload`` (decode's columns to
 # the device), ``copy.fetch`` (device_wait's reads) and
-# ``archive.prefilter``.  Compile events piggyback on the trace counters: a
+# ``archive.prefilter``.  Counters ``sweep.points`` and ``sweep.rows``
+# (``_count_chunk``) count each raw chunk's points and the layer rows the
+# network stage evaluates for them.  Compile events piggyback on the trace
+# counters: a
 # dispatch that bumps trace_count/ppa_trace_count charges its duration to
 # histogram ``compile.L<layers>`` — per-layer-bucket compile attribution.
 
@@ -140,12 +143,24 @@ def _compile_mark() -> int:
     return _TRACE_COUNT + _PPA_TRACE_COUNT
 
 
-def _workload_bucket(workload) -> str:
+def _stage_depth(workload) -> int:
     # the padded layer count the evaluator compiles per: a stack's
     # trailing axis, or the bucket a plain workload is padded to
     if isinstance(workload, StackedWorkload):
-        return f"L{int(np.shape(workload.layers.H)[-1])}"
-    return f"L{layer_bucket(workload_layers(workload))}"
+        return int(np.shape(workload.layers.H)[-1])
+    return layer_bucket(workload_layers(workload))
+
+
+def _workload_bucket(workload) -> str:
+    return f"L{_stage_depth(workload)}"
+
+
+def _count_chunk(tr, n: int, workload) -> None:
+    """Count a raw chunk of ``n`` points (call under ``tr.enabled``):
+    ``sweep.points``, and ``sweep.rows``, the layer rows the network
+    stage evaluates for them (points x the chunk's bucket depth)."""
+    tr.counter("sweep.points", n)
+    tr.counter("sweep.rows", n * _stage_depth(workload))
 
 
 def _note_compiles(tr, mark: int, start_ns: int, workload,
@@ -897,7 +912,7 @@ def evaluate_space_streaming(
                                   max_points=max_points, seed=seed,
                                   telemetry=telemetry), tr):
             if tr.enabled:
-                tr.counter("sweep.points", len(idx))
+                _count_chunk(tr, len(idx), workload)
             for res, fidx, _aux in pruner.feed(cfg, idx, workload):
                 yield res, fidx
         for res, fidx, _aux in pruner.finish():
@@ -909,7 +924,7 @@ def evaluate_space_streaming(
                               telemetry=telemetry), tr):
         n_raw = len(idx)
         if tr.enabled:
-            tr.counter("sweep.points", n_raw)
+            _count_chunk(tr, n_raw, workload)
         pending = _traced_dispatch(tr, cfg, workload, model, chunk_size)
         res = _traced_finish(tr, pending)
         if budget is not None:

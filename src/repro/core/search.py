@@ -55,8 +55,9 @@ from repro.core.coexplore import (COEXPLORE_METRICS, CoexploreFront,
                                   ModelEntry, _fold_joint, _pe_codes,
                                   accuracy_matrix, plan_joint_walk)
 from repro.core.dse import (DEFAULT_CHUNK_SIZE, ParetoArchive, _PPAView,
-                            _pad_config, _ppa_stage, _traced_dispatch,
-                            _traced_finish, dispatch_chunk, finish_chunk)
+                            _pad_config, _ppa_stage, _stage_depth,
+                            _traced_dispatch, _traced_finish, dispatch_chunk,
+                            finish_chunk)
 from repro.core.ppa import PPAModels
 from repro.obs import as_tracer
 
@@ -675,6 +676,8 @@ def search_front(
                         else dispatch_chunk(cfg, stacked, cost_model,
                                             pad_to=chunk_size,
                                             model_ids=model_ids)
+                if traced:
+                    tr.counter("sweep.rows", len(idx) * _stage_depth(stacked))
                 c += 1
                 inflight.append((pending, idx, mids, codes))
                 while len(inflight) >= cap:

@@ -59,8 +59,9 @@ from repro.core.arch import (AcceleratorConfig, PE_TYPE_NAMES, config_rows,
 from repro.core.constraints import Budget, BudgetStats, apply_budget
 from repro.core.costmodel import as_cost_model
 from repro.core.dse import (DEFAULT_CHUNK_SIZE, ParetoArchive, TwoStagePruner,
-                            _objective_columns, _traced_dispatch,
-                            _traced_finish, dispatch_chunk, finish_chunk)
+                            _count_chunk, _objective_columns,
+                            _traced_dispatch, _traced_finish, dispatch_chunk,
+                            finish_chunk)
 from repro.obs import NULL_TRACER, as_tracer, timed_iter
 
 # In-flight chunks per shard: 2 = classic double buffering (one chunk
@@ -365,7 +366,7 @@ def _sharded_space_events(
             break
         s = c % shards
         if traced:
-            tracer.counter("sweep.points", len(idx))
+            _count_chunk(tracer, len(idx), workload)
         if use_prune:
             with jax.default_device(shard_device(devices, s)):
                 for res, fidx, _aux in pruners[s].feed(cfg, idx, workload):

@@ -322,6 +322,55 @@ def touched_experts(experts: int, topk: int, routed_tokens: int) -> float:
     return float(np.clip(t, float(min(topk, experts)), float(experts)))
 
 
+def _shared_stream_rows(add, tag: str, heads: int, k_width: int,
+                        v_width: int, kvlen: int, count: int) -> None:
+    """Decode score and value rows of ``heads`` query heads that share one
+    streamed cache: M = heads, so each cache word is read once per layer
+    and sequence and feeds ``heads`` MACs.  The score product streams
+    ``kvlen`` x ``k_width`` words, the value product ``kvlen`` x
+    ``v_width``; both are ``attn_kv`` rows."""
+    ir = dict(kind=KIND_ATTN_KV, acc_class=ACC_ATTN)
+    add(f"qk_{tag}", heads, k_width, kvlen, count,
+        stream_words=float(kvlen) * k_width, **ir)
+    add(f"av_{tag}", heads, kvlen, v_width, count,
+        stream_words=float(kvlen) * v_width, **ir)
+
+
+def _mla_rows(add, cfg, tokens: int, kvlen: int, decode: bool) -> None:
+    """Attention rows of multi-head latent attention (DeepSeek-V2/V3).
+
+    Per layer the query goes through its low-rank path (``wq_a`` then
+    ``wq_b``) and the new position's latent is ``wkv_a``'s output: the
+    ``kv_lora_rank``-wide ``c_kv`` plus the ``qk_rope_head_dim``-wide
+    ``k_rope``, which is all the cache holds.  Decode runs the absorbed
+    form: ``q_absorb`` maps each head's no-rope query into the latent
+    (``W_UK``), every head scores against the one shared latent stream
+    (``qk_latent``) and sums ``c_kv`` by its probabilities (``av_latent``),
+    and ``v_absorb`` maps the result out per head (``W_UV``).  Prefill
+    and training run the naive form: ``kv_b`` decompresses the latent into
+    per-head keys and values, then per-head score and value products on
+    the resident path, as the other attention rows do."""
+    L, hq, d = cfg.n_layers, cfg.n_heads, cfg.d_model
+    ql, r = cfg.q_lora_rank, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    if ql <= 0:
+        raise ValueError(f"{cfg.name}: latent attention is priced with a "
+                         f"query low-rank path (q_lora_rank > 0)")
+    attn = dict(acc_class=ACC_ATTN)
+    add("wq_a", tokens, d, ql, L, **attn)
+    add("wq_b", tokens, ql, hq * (nope + rope), L, **attn)
+    add("wkv_a", tokens, d, r + rope, L, **attn)
+    if decode:
+        add("q_absorb", tokens, nope, r, L * hq, **attn)
+        _shared_stream_rows(add, "latent", hq, r + rope, r, kvlen, L)
+        add("v_absorb", tokens, r, dv, L * hq, **attn)
+    else:
+        add("kv_b", tokens, r, hq * (nope + dv), L, **attn)
+        add("qk", tokens, nope + rope, kvlen, L * hq, **attn)
+        add("av", tokens, kvlen, dv, L * hq, **attn)
+    add("wo", tokens, hq * dv, d, L, **attn)
+
+
 def transformer_workload(cfg, seq: int, batch: int, mode: str = "train",
                          name: str | None = None) -> Workload:
     """Extract per-layer GEMMs from a repro.configs ArchConfig-like object.
@@ -339,6 +388,10 @@ def transformer_workload(cfg, seq: int, batch: int, mode: str = "train",
     layers shaped by the ACTIVE top-k compute with ``active_frac`` set
     from the expected touched-expert count, and always-on shared experts
     as plain resident GEMMs.
+
+    Configs with ``cfg.kv_lora_rank > 0`` use multi-head latent
+    attention (``_mla_rows``): absorbed rows over one shared latent cache
+    in decode, the naive decompressed rows in prefill and training.
     """
     d, L = cfg.d_model, cfg.n_layers
     hq, hkv = cfg.n_heads, cfg.kv_heads
@@ -353,7 +406,9 @@ def transformer_workload(cfg, seq: int, batch: int, mode: str = "train",
         names.append(tag)
 
     attn_layers = getattr(cfg, "attn_layers", L if hq > 0 else 0)
-    if attn_layers:
+    if getattr(cfg, "kv_lora_rank", 0):
+        _mla_rows(add, cfg, tokens, kvlen, decode)
+    elif attn_layers:
         add("wq", tokens, d, hq * dh, attn_layers, acc_class=ACC_ATTN)
         add("wk", tokens, d, hkv * dh, attn_layers, acc_class=ACC_ATTN)
         add("wv", tokens, d, hkv * dh, attn_layers, acc_class=ACC_ATTN)

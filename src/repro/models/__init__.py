@@ -7,8 +7,8 @@
   encdec         -> encdec (whisper-style)
 """
 
-from repro.models import (cnn, encdec, hybrid, layers, mamba, moe, rwkv,
-                          ssm_common, transformer)
+from repro.models import (cnn, encdec, hybrid, layers, mamba, mla, moe,
+                          rwkv, ssm_common, transformer)
 
 
 def family_module(cfg):
@@ -24,5 +24,5 @@ def family_module(cfg):
     raise ValueError(f"unknown family {fam}")
 
 
-__all__ = ["cnn", "encdec", "hybrid", "layers", "mamba", "moe", "rwkv",
-           "ssm_common", "transformer", "family_module"]
+__all__ = ["cnn", "encdec", "hybrid", "layers", "mamba", "mla", "moe",
+           "rwkv", "ssm_common", "transformer", "family_module"]

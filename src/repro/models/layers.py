@@ -126,6 +126,14 @@ def qdense(x: jnp.ndarray, w: jnp.ndarray, qcfg: QuantConfig,
     dequantized inline — the graph then reads u8/s8 codes from HBM and
     dequantizes in VMEM, mirroring kernels/quant_matmul.
     """
+    x, w = qoperands(x, w, qcfg, cast)
+    return x @ w
+
+
+def qoperands(x: jnp.ndarray, w, qcfg: QuantConfig, cast=None):
+    """The two operands ``qdense`` multiplies, after dequantization,
+    fake-quant and the compute-dtype cast; for callers that reorder the
+    product (absorbed latent attention)."""
     if isinstance(w, dict):
         from repro.quant import pack as QP
         mode = next(k.split("__", 1)[1] for k in w if k.startswith("codes__"))
@@ -143,7 +151,7 @@ def qdense(x: jnp.ndarray, w: jnp.ndarray, qcfg: QuantConfig,
     if ct is not None:
         x = x.astype(ct)
         w = w.astype(ct)
-    return x @ w
+    return x, w
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,6 @@
 """Decoder-only transformer LM family (qwen3 / gemma2 / gemma3 / smollm /
-qwen2-vl backbone / MoE variants).
+qwen2-vl backbone / MoE variants / DeepSeek-V3's latent attention, in
+``repro.models.mla``).
 
 Layers are stacked along a leading L axis and executed with
 ``jax.lax.scan`` so the compiled HLO contains one layer body regardless of
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import layers as L
+from repro.models import mla as MLA
 from repro.models import moe as MOE
 from repro.quant.qconfig import preset
 
@@ -57,6 +59,14 @@ def attn_spec(cfg, is_global: bool = True) -> L.AttnSpec:
         query_scale=cfg.query_scale)
 
 
+def _attn_init(key, cfg, dtype) -> Params:
+    """Attention params: latent attention when ``cfg.kv_lora_rank`` is
+    set, else the unified GQA layer."""
+    if cfg.kv_lora_rank:
+        return MLA.mla_init(key, cfg, dtype)
+    return L.attn_init(key, cfg.d_model, attn_spec(cfg), dtype)
+
+
 def dataclasses_replace_kv(spec: L.AttnSpec, kv: int) -> L.AttnSpec:
     import dataclasses as _dc
     return _dc.replace(spec, kv_heads=kv)
@@ -70,7 +80,7 @@ def _stacked_layer_init(key, cfg, n: int, moe: bool, dtype) -> Params:
     """Init n identical layers with params stacked on a leading axis."""
     def one(k):
         ka, km, k1, k2 = jax.random.split(k, 4)
-        p = {"attn": L.attn_init(ka, cfg.d_model, attn_spec(cfg), dtype),
+        p = {"attn": _attn_init(ka, cfg, dtype),
              "ln1": jnp.zeros((cfg.d_model,), dtype) if cfg.zero_centered_norm
              else jnp.ones((cfg.d_model,), dtype),
              "ln2": jnp.zeros((cfg.d_model,), dtype) if cfg.zero_centered_norm
@@ -100,7 +110,7 @@ def init_params(cfg, key) -> Params:
     if cfg.first_dense:  # deepseek: leading dense layer(s), unstacked
         def one_dense(k):
             ka, km = jax.random.split(k)
-            return {"attn": L.attn_init(ka, cfg.d_model, attn_spec(cfg), dtype),
+            return {"attn": _attn_init(ka, cfg, dtype),
                     "mlp": L.mlp_init(km, cfg.d_model,
                                       cfg.dense_d_ff or cfg.d_ff, True, dtype),
                     "ln1": jnp.ones((cfg.d_model,), dtype),
@@ -117,12 +127,13 @@ def init_params(cfg, key) -> Params:
 # ---------------------------------------------------------------------------
 
 def _block(p: Params, x, cfg, qcfg, positions, is_global, cache=None,
-           moe: bool = False, attn_mode: str = "dyn"):
+           moe: bool = False, attn_mode: str = "dyn", absorb: bool = False):
     """One transformer block.
 
     attn_mode: 'dyn' (traced is_global flag, scan-homogeneous masking —
     the baseline), 'local' (static block-banded window — perf variant),
-    'global' (static full causal).
+    'global' (static full causal).  ``absorb`` runs latent attention in
+    its absorbed (decode) form.
     """
     spec_g = attn_spec(cfg, True)
     if attn_mode == "dyn":
@@ -136,9 +147,13 @@ def _block(p: Params, x, cfg, qcfg, positions, is_global, cache=None,
         window = 1 << 30
     x = L.shard_batch(x)
     h = L.rmsnorm(x, p["ln1"], zero_centered=cfg.zero_centered_norm)
-    attn_out, new_cache = _attention_dynwin(
-        p["attn"], h, spec_g, qcfg, positions, window, cache,
-        block_local=(attn_mode == "local" and cache is None), cfg=cfg)
+    if cfg.kv_lora_rank:
+        attn_out, new_cache = MLA.mla_attention(
+            p["attn"], h, cfg, qcfg, positions, cache, absorb)
+    else:
+        attn_out, new_cache = _attention_dynwin(
+            p["attn"], h, spec_g, qcfg, positions, window, cache,
+            block_local=(attn_mode == "local" and cache is None), cfg=cfg)
     x = x + attn_out.astype(x.dtype)
     h = L.rmsnorm(x, p["ln2"], zero_centered=cfg.zero_centered_norm)
     if moe:
@@ -238,11 +253,12 @@ def _attention_dynwin(p, x, spec, qcfg, positions, window, cache,
     return L.qdense(out, p["wo"], qcfg), new_cache
 
 
-def _backbone(params, x, cfg, positions, caches=None):
+def _backbone(params, x, cfg, positions, caches=None, absorb=False):
     """Embed-less forward over all layers. x: (B, S, D) hidden states.
 
     caches: None (train/prefill-no-cache) or pytree with leading L axis for
     the scanned layers (+ list for dense layers). Returns (y, new_caches).
+    absorb: latent attention in its absorbed form (decode steps).
     """
     qcfg = preset(cfg.pe_type)
     is_moe = cfg.moe_experts > 0
@@ -253,7 +269,7 @@ def _backbone(params, x, cfg, positions, caches=None):
         p = params["dense_layers"][i]
         c = None if caches is None else caches["dense"][i]
         x, c = _block(p, x, cfg, qcfg, positions, jnp.asarray(True), c,
-                      moe=False)
+                      moe=False, absorb=absorb)
         dense_caches.append(c)
 
     # perf variant: pattern-grouped scan with static block-banded local
@@ -267,7 +283,7 @@ def _backbone(params, x, cfg, positions, caches=None):
         h = carry
         layer_params, flag, cache = xs
         h, new_cache = _block(layer_params, h, cfg, qcfg, positions, flag,
-                              cache, moe=is_moe)
+                              cache, moe=is_moe, absorb=absorb)
         return h, new_cache
 
     scan_caches = None if caches is None else caches["scan"]
@@ -366,13 +382,14 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
         spec = dataclasses_replace_kv(spec, cfg.kv_replicate_to)
     n_scan = cfg.n_layers - cfg.first_dense
 
-    def one(_):
+    def one(_=None):
+        if cfg.kv_lora_rank:  # the latent cache: no per-head K/V
+            return MLA.make_mla_cache(batch, max_len, cfg, dtype)
         return L.make_cache(batch, max_len, spec, dtype)
 
     scan_caches = jax.vmap(one)(jnp.arange(n_scan))
     # vmap over make_cache gives index shape (n_scan,) — keep per-layer idx
-    dense = [L.make_cache(batch, max_len, spec, dtype)
-             for _ in range(cfg.first_dense)]
+    dense = [one() for _ in range(cfg.first_dense)]
     return {"dense": dense, "scan": scan_caches}
 
 
@@ -395,5 +412,5 @@ def decode_step(params, token, cfg, cache, positions=None):
         pos = (idx[0] if idx.ndim else idx).astype(jnp.int32)
         positions = jnp.full((b, 1), pos, jnp.int32)
     x = _embed(params, token, cfg)
-    x, cache = _backbone(params, x, cfg, positions, cache)
+    x, cache = _backbone(params, x, cfg, positions, cache, absorb=True)
     return _logits(params, x, cfg), cache

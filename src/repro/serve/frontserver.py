@@ -82,7 +82,7 @@ from repro.core.coexplore import (COEXPLORE_METRICS, CoexploreFront,
 from repro.core.constraints import Budget, BudgetColumns, BudgetStats
 from repro.core.costmodel import as_cost_model
 from repro.core.dse import (DEFAULT_CHUNK_SIZE, ParetoArchive,
-                            chunk_dominators,
+                            chunk_dominators, _count_chunk,
                             _traced_dispatch, _traced_finish,
                             fold_budget_chunk)
 from repro.core.shard import space_signature, workloads_signature
@@ -575,7 +575,7 @@ class FrontServer:
             walk.started = True
             codes = np.asarray(cfg.pe_type).astype(np.int64)
             if tr.enabled:
-                tr.counter("sweep.points", len(idx))
+                _count_chunk(tr, len(idx), wl)
             pending = _traced_dispatch(tr, cfg, wl, self._model,
                                        self.chunk_size, model_ids=model_ids)
             walk.pending.append((pending, mids, codes, idx))

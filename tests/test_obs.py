@@ -4,6 +4,7 @@ instrumentation contract — fronts bit-identical with telemetry on or off
 near-zero disabled cost, one Chrome-trace lane per shard, checkpoint and
 serving events, and the registry-derived benchmark helpers."""
 
+import copy
 import glob
 import json
 import math
@@ -668,6 +669,47 @@ PER_CHUNK = ("copy.upload", "copy.codes", "copy.fetch", "sweep.dispatch",
              "sweep.best")
 
 
+def _model_depths(models):
+    """Each model's bucket depth, the layer rows a point of it costs."""
+    plan = plan_joint_walk(models, TINY_SPACE)
+    return np.array([int(np.shape(plan.stacked[b].layers.H)[-1])
+                     for b in plan.bucket_of])
+
+
+class TestRowsCounter:
+    """``sweep.rows``: each evaluated point's bucket depth, summed."""
+
+    @pytest.mark.parametrize("shards", (None, 2))
+    def test_walk(self, tiny_models, shards):
+        tr = Tracer(rss_interval_s=0)
+        front = coexplore_front(tiny_models, TINY_SPACE, chunk_size=CHUNK,
+                                shards=shards, telemetry=tr)
+        n = front.points_evaluated
+        assert n == space_size(TINY_SPACE) * len(tiny_models)
+        mids = np.arange(n) // space_size(TINY_SPACE)
+        c = tr.registry.counters
+        assert c["sweep.points"].value == n
+        assert c["sweep.rows"].value == _model_depths(tiny_models)[mids].sum()
+
+    def test_search(self, tiny_models):
+        tr = Tracer(rss_interval_s=0)
+        _, seen = _search(tiny_models, tr)
+        mids = np.concatenate([i for i, _ in seen]) // space_size(TINY_SPACE)
+        assert tr.registry.counters["sweep.rows"].value \
+            == _model_depths(tiny_models)[mids].sum()
+
+    @pytest.mark.parametrize("shards", (None, 2))
+    def test_plain_workload_walk(self, workload, shards):
+        from repro.core.workloads import layer_bucket, workload_layers
+        with Tracer(rss_interval_s=0) as tr:
+            pareto_front_streaming(workload, TINY_SPACE, chunk_size=CHUNK,
+                                   metrics=METRICS, shards=shards,
+                                   telemetry=tr)
+        c = tr.registry.counters
+        assert c["sweep.rows"].value == c["sweep.points"].value \
+            * layer_bucket(workload_layers(workload))
+
+
 class TestChunkPathSpans:
 
     def test_walk_records_every_span_per_chunk(self, tiny_models):
@@ -866,6 +908,25 @@ class TestBenchReaders:
         # a program without these spans and counters reads nothing
         assert read(_readings(Tracer(record_events=False,
                                      rss_interval_s=0), 1.0)) is None
+
+    def test_network_ns_per_row(self, traced_cells):
+        """Device seconds of the network stage over ``sweep.rows``; no
+        device plane (the CPU) or no counter (a program without it)
+        reads nothing."""
+        from bench import registry
+        read = registry.metric_reader("network_ns_per_row.sweep")
+        for cell in ("paper_sweep", "llm_search"):
+            r = copy.copy(traced_cells[cell])
+            assert read(r) is None
+            r.trace = dict(r.trace, exec_s={"_lane_layers": 2e-4,
+                                            "_network_sums": 1e-3,
+                                            "_ppa_stage": 5.0})
+            assert read(r) == pytest.approx(
+                1.2e-3 / r.counters["sweep.rows"] * 1e9)
+            no_rows = dict(r.counters)
+            del no_rows["sweep.rows"]
+            r.counters = no_rows
+            assert read(r) is None
 
     def test_readers_add_up_against_the_registry(self, traced_cells):
         from bench import registry

@@ -117,15 +117,23 @@ class TestFirstDenseFix:
 class TestMacsIdentities:
     @pytest.mark.parametrize("arch", sorted(ARCH_IDS))
     def test_prefill_is_seq_times_decode(self, arch):
-        """Every extracted row's M dimension is linear in the token count
-        and nothing else differs between phases, so prefill at seq tokens
-        does exactly seq times the decode-step MACs (same context)."""
+        """Every row both phases extract has its M dimension linear in the
+        token count and nothing else differs between phases, so prefill
+        at seq tokens does exactly seq times the decode-step MACs over
+        those rows (same context).  Every config extracts the same rows
+        in both phases, except latent attention's core rows (absorbed in
+        decode, decompressed in prefill; ``TestLatentAttentionRows``)."""
         cfg = reduced(arch)
-        pre = workload_macs(transformer_workload(cfg, seq=SEQ, batch=1,
-                                                 mode="prefill"))
-        dec = workload_macs(transformer_workload(cfg, seq=SEQ, batch=1,
-                                                 mode="decode"))
-        assert pre == pytest.approx(SEQ * dec, rel=1e-6)
+        pre = transformer_workload(cfg, seq=SEQ, batch=1, mode="prefill")
+        dec = transformer_workload(cfg, seq=SEQ, batch=1, mode="decode")
+        if not cfg.kv_lora_rank:
+            assert pre.layer_names == dec.layer_names
+        shared = [n for n in pre.layer_names if n in dec.layer_names]
+
+        def macs(wl):
+            m = np.asarray(wl.layers.macs(), np.float64)
+            return sum(m[wl.layer_names.index(n)] for n in shared)
+        assert macs(pre) == pytest.approx(SEQ * macs(dec), rel=1e-6)
 
     @pytest.mark.parametrize("arch", ["deepseek-moe-16b",
                                       "phi3.5-moe-42b-a6.6b"])
@@ -150,6 +158,204 @@ class TestMacsIdentities:
     def test_llm_moe_rejects_dense_configs(self):
         with pytest.raises(ValueError):
             llm_moe("qwen3-32b")
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V3's multi-head latent attention: absorbed decode rows
+# ---------------------------------------------------------------------------
+
+# The three decode steps of the dsv3_mla_decode_mapped benchmark
+# configuration: 524,288 cached positions per step each.
+MLA_STEPS = [(16384, 32), (65536, 8), (131072, 4)]
+
+# Sha256 prefixes of the decode tables the llm_decode_mapped benchmark
+# configuration prices and of prefill tables of the same models, taken
+# before latent attention was added: no other config's table moves.
+TABLE_DIGESTS = {
+    "qwen3-32b-decode": "6688dd7c849eed00",
+    "deepseek-moe-16b-decode": "cae8ead88b88d43a",
+    "phi35-moe-decode": "a62492f0b1da22a6",
+    "qwen3-32b-train": "aab61f3f90762768",
+    "deepseek-moe-16b-train": "fa4a30a9324d8b80",
+    "phi3.5-moe-42b-a6.6b-train": "cc8244882ae09ed7",
+}
+
+
+def _table_digest(wl):
+    import hashlib
+    h = hashlib.sha256(wl.name.encode())
+    for n in wl.layer_names:
+        h.update(n.encode())
+    for f in LayerSpec._fields:
+        h.update(np.asarray(getattr(wl.layers, f), np.float32).tobytes())
+    return h.hexdigest()[:16]
+
+
+class TestLatentAttentionRows:
+    @staticmethod
+    def _rows(wl):
+        cols = ("W", "C", "K", "count", "batch", "kind", "stream_words",
+                "acc_class")
+        return {n: tuple(float(np.asarray(getattr(wl.layers, f))[i])
+                         for f in cols)
+                for i, n in enumerate(wl.layer_names)}
+
+    @pytest.mark.parametrize("context,batch", MLA_STEPS)
+    def test_decode_rows_closed_form(self, context, batch):
+        """The 16 rows of one decode step, (M, Kd, N, count) each, from
+        the published widths: the absorbed attention rows, then the
+        dense, expert, shared-expert, router and head rows."""
+        cfg = get("deepseek-v3")
+        wl = llm_decode("deepseek-v3", context=context, batch=batch)
+        assert wl.name == f"deepseek-v3-decode-c{context}-b{batch}"
+        L, hq, d = 61, 128, 7168
+        ql, r, nope, p, dv = 1536, 512, 128, 64, 128
+        touched = touched_experts(256, 8, batch)
+        gemm_, attn_kv, gated = float(KIND_GEMM), float(KIND_ATTN_KV), 3.0
+        A, F, X, D = 1.0, 2.0, 3.0, 0.0        # accuracy classes
+        want = {
+            "wq_a": (1, d, ql, L, gemm_, 0, A),
+            "wq_b": (1, ql, hq * (nope + p), L, gemm_, 0, A),
+            "wkv_a": (1, d, r + p, L, gemm_, 0, A),
+            "q_absorb": (1, nope, r, L * hq, gemm_, 0, A),
+            "qk_latent": (hq, r + p, context, L, attn_kv,
+                          context * (r + p), A),
+            "av_latent": (hq, context, r, L, attn_kv, context * r, A),
+            "v_absorb": (1, r, dv, L * hq, gemm_, 0, A),
+            "wo": (1, hq * dv, d, L, gemm_, 0, A),
+            "ffn_in": (1, d, 2 * 18432, 3, gemm_, 0, F),
+            "ffn_out": (1, 18432, d, 3, gemm_, 0, F),
+            "moe_in": (8, d, 2 * 2048, 58, gated, 0, X),
+            "moe_out": (8, 2048, d, 58, gated, 0, X),
+            "moe_shared_in": (1, d, 2 * 2048, 58, gemm_, 0, X),
+            "moe_shared_out": (1, 2048, d, 58, gemm_, 0, X),
+            "router": (1, d, 256, 58, gemm_, 0, F),
+            "lm_head": (1, d, 129280, 1, gemm_, 0, D),
+        }
+        assert wl.layer_names == tuple(want)
+        got = self._rows(wl)
+        for name, (m, kd, n, count, kind, stream, acc) in want.items():
+            assert got[name] == (m, kd, n, count, batch, kind, stream,
+                                 acc), name
+        frac = np.asarray(wl.layers.active_frac)
+        gated_rows = np.asarray(wl.layers.kind) == gated
+        np.testing.assert_array_equal(
+            frac[gated_rows], np.float32(1.0 / touched))
+        assert np.all(frac[~gated_rows] == 1.0)
+        assert cfg.n_layers == L and cfg.first_dense == 3
+
+    def test_stream_words_independent_of_heads(self):
+        """The latent is one stream shared by the heads: M follows the
+        head count while the streamed words do not."""
+        cfg = get("deepseek-v3")
+        rows = {h: self._rows(llm_decode(cfg.replace(n_heads=h),
+                                         context=16384, batch=32))
+                for h in (32, 128)}
+        for name in ("qk_latent", "av_latent"):
+            assert rows[32][name][0] == 32 and rows[128][name][0] == 128
+            assert rows[32][name][6] == rows[128][name][6]
+        assert rows[128]["qk_latent"][6] == 16384 * 576
+        assert rows[128]["av_latent"][6] == 16384 * 512
+
+    @pytest.mark.parametrize("context,batch", MLA_STEPS)
+    def test_macs_equal_absorbed_einsums(self, context, batch):
+        """Each attention row's MACs are the multiply-adds of the einsums
+        the zoo's absorbed decode (``repro.models.mla``) computes for one
+        token per sequence: the product of every index's size, times the
+        layer count."""
+        L = 61
+        size = dict(b=batch, s=1, d=7168, q=1536, e=128 * 192, k=576,
+                    h=128, n=128, r=512, p=64, t=context, v=128,
+                    z=128 * 128)
+        einsums = {
+            "wq_a": ["bsd,dq->bsq"],
+            "wq_b": ["bsq,qe->bse"],
+            "wkv_a": ["bsd,dk->bsk"],
+            "q_absorb": ["bshn,rhn->bshr"],
+            "qk_latent": ["bshr,btr->bhst", "bshp,btp->bhst"],
+            "av_latent": ["bhst,btr->bshr"],
+            "v_absorb": ["bshr,rhv->bshv"],
+            "wo": ["bsz,zd->bsd"],
+        }
+        wl = llm_decode("deepseek-v3", context=context, batch=batch)
+        macs = np.asarray(wl.layers.macs(), np.float64)
+        for name, specs in einsums.items():
+            want = L * sum(
+                float(np.prod([size[c] for c in set(e.replace(",", "")
+                                                    .split("->")[0])]))
+                for e in specs)
+            assert macs[wl.layer_names.index(name)] \
+                == pytest.approx(want, rel=1e-6), name
+
+    def test_prefill_rows_are_naive(self):
+        """Prefill decompresses the latent (``kv_b``) and attends per
+        head on the resident path; nothing is streamed."""
+        wl = transformer_workload(get("deepseek-v3"), seq=4096, batch=1,
+                                  mode="prefill")
+        rows = self._rows(wl)
+        assert wl.layer_names[:7] == ("wq_a", "wq_b", "wkv_a", "kv_b",
+                                      "qk", "av", "wo")
+        assert rows["kv_b"][:4] == (4096, 512, 128 * 256, 61)
+        assert rows["qk"][:4] == (4096, 192, 4096, 61 * 128)
+        assert rows["av"][:4] == (4096, 4096, 128, 61 * 128)
+        assert not np.any(np.asarray(wl.layers.kind) == float(KIND_ATTN_KV))
+
+    def test_latent_attention_needs_a_query_low_rank_path(self):
+        cfg = get("deepseek-v3").replace(q_lora_rank=0)
+        with pytest.raises(ValueError, match="q_lora_rank"):
+            llm_decode(cfg, context=1024)
+
+    def test_other_tables_unchanged(self):
+        """The tables of every other config are bit for bit those from
+        before latent attention."""
+        tables = {
+            "qwen3-32b-decode": llm_decode("qwen3-32b", context=8192),
+            "deepseek-moe-16b-decode": llm_decode("deepseek-moe-16b",
+                                                  context=4096),
+            "phi35-moe-decode": llm_moe("phi3.5-moe-42b-a6.6b", seq=512,
+                                        mode="decode"),
+        }
+        for a in ("qwen3-32b", "deepseek-moe-16b", "phi3.5-moe-42b-a6.6b"):
+            tables[a + "-train"] = transformer_workload(get(a), seq=2048,
+                                                        batch=4, mode="train")
+        assert {k: _table_digest(w) for k, w in tables.items()} \
+            == TABLE_DIGESTS
+
+    def test_long_context_rows_cost_exactly(self):
+        """At 131,072 cached positions operands of the cost model's
+        floor and ceil quotients pass 2**24, past ``floor_div``'s promise
+        (the value row's C x H x W x bits reaches 2**29); the float32
+        cost model still matches the same formulas in float64 on the
+        mapped grid's corners."""
+        from repro.core.arch import AcceleratorConfig
+        wl = llm_decode("deepseek-v3", context=131072, batch=4)
+        grid = dict(pe_rows=(8., 32.), pe_cols=(8., 32.),
+                    gbuf_kb=(54., 432.), spad_ifmap=(12., 24.),
+                    spad_filter=(112., 448.), spad_psum=(16., 32.),
+                    pe_type=(0., 2., 4.), bandwidth_gbps=(12.8, 51.2),
+                    mapping=(0., 1., 47., 119.))
+        pts = np.stack(np.meshgrid(*grid.values(), indexing="ij"),
+                       -1).reshape(-1, len(grid))
+        cfg = AcceleratorConfig(**{
+            k: pts[:, i].astype(np.int32 if k == "pe_type" else np.float32)
+            for i, k in enumerate(grid)})
+        clock = np.float32(1.0)
+        f32 = jax.vmap(lambda c: jax.vmap(
+            layer_cost, in_axes=(0, None, None))(wl.layers, c, clock))(cfg)
+        with jax.enable_x64(True):
+            lay64 = LayerSpec(*[np.asarray(x, np.float64)
+                                for x in wl.layers])
+            cfg64 = cfg._replace(**{
+                k: np.asarray(v, np.float64)
+                for k, v in cfg._asdict().items() if k != "pe_type"})
+            f64 = jax.vmap(lambda c: jax.vmap(
+                layer_cost, in_axes=(0, None, None))(lay64, c,
+                                                     np.float64(1.0)))(cfg64)
+            f64 = {k: np.asarray(v) for k, v in f64._asdict().items()}
+        for k in ("cycles", "dram_bits", "gbuf_bits", "energy_pj",
+                  "utilization"):
+            got = np.asarray(getattr(f32, k), np.float64)
+            np.testing.assert_allclose(got, f64[k], rtol=1e-5, err_msg=k)
 
 
 # ---------------------------------------------------------------------------
