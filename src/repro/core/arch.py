@@ -22,6 +22,7 @@ from __future__ import annotations
 
 from typing import Iterable, Iterator, NamedTuple, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -64,6 +65,46 @@ class AcceleratorConfig(NamedTuple):
     @property
     def num_pes(self):
         return self.pe_rows * self.pe_cols
+
+
+# The float32 fields of a config, in field order: the rows of a
+# ``PackedConfig``'s ``knobs``.
+KNOB_FIELDS = tuple(f for f in AcceleratorConfig._fields if f != "pe_type")
+
+
+class PackedConfig(NamedTuple):
+    """A batched config as one transfer carries it to the device: two
+    buffers in place of nine, since a copy costs per buffer far more than
+    per byte.  ``knobs`` stacks the float32 fields (``KNOB_FIELDS``) as
+    rows; ``pe_type`` holds the int32 codes.  The jitted stages unpack it
+    (``as_config``) as they trace, so unpacking dispatches nothing."""
+
+    knobs: jnp.ndarray    # (len(KNOB_FIELDS), lanes) float32
+    pe_type: jnp.ndarray  # (lanes,) int32
+
+
+def pack_config(cfg: AcceleratorConfig) -> PackedConfig:
+    """A batched config packed on the host into a ``PackedConfig``, in the
+    device dtypes (the ones a jitted stage would convert host fields to);
+    fields still on the device come back in one batched read."""
+    cfg = jax.device_get(cfg)
+    return PackedConfig(
+        knobs=np.stack([np.asarray(getattr(cfg, f), np.float32)
+                        for f in KNOB_FIELDS]),
+        pe_type=np.asarray(cfg.pe_type, np.int32))
+
+
+def as_config(cfg: AcceleratorConfig | PackedConfig) -> AcceleratorConfig:
+    """The ``AcceleratorConfig`` a jitted stage computes on: a packed
+    config's rows, or the config itself.  The rows pass an optimization
+    barrier, so XLA fuses the stage's arithmetic as it does for nine
+    separate arguments: fused into the row slicing, the PPA stage's clock
+    came out different in its last bits."""
+    if not isinstance(cfg, PackedConfig):
+        return cfg
+    return jax.lax.optimization_barrier(AcceleratorConfig(
+        pe_type=cfg.pe_type,
+        **{f: cfg.knobs[i] for i, f in enumerate(KNOB_FIELDS)}))
 
 
 def make_config(
@@ -208,77 +249,78 @@ def subsample_indices(n: int, max_points: int | None,
     return np.sort(rng.choice(n, size=max_points, replace=False))
 
 
-def _cols_to_config(cols: dict, telemetry=None) -> AcceleratorConfig:
-    """The host columns copied to the device, under a ``copy.upload``
-    span."""
-    with as_tracer(telemetry).span("upload", cat="copy"):
-        return AcceleratorConfig(
-            pe_rows=jnp.asarray(cols["pe_rows"], jnp.float32),
-            pe_cols=jnp.asarray(cols["pe_cols"], jnp.float32),
-            gbuf_kb=jnp.asarray(cols["gbuf_kb"], jnp.float32),
-            spad_ifmap=jnp.asarray(cols["spad_ifmap"], jnp.float32),
-            spad_filter=jnp.asarray(cols["spad_filter"], jnp.float32),
-            spad_psum=jnp.asarray(cols["spad_psum"], jnp.float32),
-            pe_type=jnp.asarray(cols["pe_type"], jnp.int32),
-            bandwidth_gbps=jnp.asarray(cols["bandwidth_gbps"], jnp.float32),
-            mapping=jnp.asarray(cols["mapping"], jnp.float32),
-        )
+# The dtypes a decoded config reaches the device in: float32 knobs and
+# int32 PE-type codes.
+CONFIG_DTYPES = {f: np.int32 if f == "pe_type" else np.float32
+                 for f in AcceleratorConfig._fields}
 
 
-def space_points(indices: np.ndarray,
-                 space: dict | None = None,
-                 telemetry=None) -> AcceleratorConfig:
-    """Decode flat space indices into a batched config via mixed radix.
+def space_columns(indices: np.ndarray,
+                  space: dict | None = None) -> AcceleratorConfig:
+    """Decode flat space indices into a batched config of HOST numpy
+    columns, already in the device dtypes (``CONFIG_DTYPES``).
 
     Index order matches ``itertools.product`` over the fields in
     ``AcceleratorConfig._fields`` order (last axis varies fastest), so
-    ``space_points(np.arange(space_size()))`` reproduces the historical
-    ``enumerate_space()`` exactly — but any index subset decodes in O(len)
-    without materializing the grid.  ``telemetry=`` times the copy of the
-    decoded columns to the device (``copy.upload``).
+    ``space_columns(np.arange(space_size()))`` is the whole grid in
+    ``enumerate_space()`` order — but any index subset decodes in O(len)
+    without materializing the grid.
     """
     axes = _space_axes(space)
     idx = np.asarray(indices, np.int64)
     radices = np.array([len(a) for a in axes], np.int64)
     # strides[i] = product of radix sizes of the faster-varying axes after i
     strides = np.concatenate([np.cumprod(radices[::-1])[::-1][1:], [1]])
-    keys = AcceleratorConfig._fields
-    cols = {k: axes[i][(idx // strides[i]) % radices[i]]
-            for i, k in enumerate(keys)}
-    return _cols_to_config(cols, telemetry)
+    return AcceleratorConfig(*[
+        axes[i].astype(CONFIG_DTYPES[k])[(idx // strides[i]) % radices[i]]
+        for i, k in enumerate(AcceleratorConfig._fields)])
+
+
+def space_points(indices: np.ndarray,
+                 space: dict | None = None,
+                 telemetry=None) -> AcceleratorConfig:
+    """``space_columns`` copied to the device in one batched transfer.
+
+    ``space_points(np.arange(space_size()))`` reproduces the historical
+    ``enumerate_space()`` exactly.  ``telemetry=`` times the copy
+    (``copy.upload``).
+    """
+    cols = space_columns(indices, space)
+    with as_tracer(telemetry).span("upload", cat="copy"):
+        return jax.device_put(cols)
 
 
 def iter_space_chunks(space: dict | None = None,
                       chunk_size: int = 4096,
                       max_points: int | None = None,
                       seed: int = 0,
-                      start_chunk: int = 0,
-                      telemetry=None) -> Iterator[
+                      start_chunk: int = 0) -> Iterator[
                           tuple[AcceleratorConfig, np.ndarray]]:
     """Lazily yield ``(config_chunk, flat_indices)`` pairs over the space.
 
     Every chunk except possibly the last has exactly ``chunk_size`` points;
     ``flat_indices`` are the global space indices of the chunk's points
-    (what ``space_points`` decodes).  Memory is O(chunk_size) regardless of
-    the total space size.  ``max_points`` subsamples the space uniformly
-    (same RNG stream as ``enumerate_space``).
+    and ``config_chunk`` their host columns (``space_columns``): the
+    evaluator copies a chunk to the device once, after padding it to the
+    compiled shape.  Memory is O(chunk_size) regardless of the total space
+    size.  ``max_points`` subsamples the space uniformly (same RNG stream
+    as ``enumerate_space``).
 
     ``start_chunk`` skips the first N chunks WITHOUT decoding them — the
     resume primitive of checkpointed walks: chunk boundaries are a pure
     function of ``(space, chunk_size, max_points, seed)``, so skipping is
-    index arithmetic, not re-evaluation.  ``telemetry=`` reaches
-    ``space_points``.
+    index arithmetic, not re-evaluation.
     """
     n = space_size(space)
     keep = subsample_indices(n, max_points, seed)
     if keep is not None:
         for lo in range(start_chunk * chunk_size, len(keep), chunk_size):
             idx = keep[lo:lo + chunk_size]
-            yield space_points(idx, space, telemetry), idx
+            yield space_columns(idx, space), idx
         return
     for lo in range(start_chunk * chunk_size, n, chunk_size):
         idx = np.arange(lo, min(lo + chunk_size, n), dtype=np.int64)
-        yield space_points(idx, space, telemetry), idx
+        yield space_columns(idx, space), idx
 
 
 def enumerate_space(space: dict | None = None,
@@ -353,9 +395,9 @@ def iter_joint_space_chunks(
         group_by_model: bool = False,
         model_groups: Sequence[Sequence[int]] | None = None,
         start_chunk: int = 0,
-        telemetry=None,
 ) -> Iterator[tuple[int | np.ndarray, AcceleratorConfig, np.ndarray]]:
-    """Lazily yield ``(model_ids, config_chunk, flat_joint_indices)``.
+    """Lazily yield ``(model_ids, config_chunk, flat_joint_indices)``,
+    each config chunk as host columns (``space_columns``).
 
     Default (mixed) mode yields dense fixed-shape chunks that freely cross
     model boundaries — ``model_ids`` is an int64 array aligned with the
@@ -378,8 +420,7 @@ def iter_joint_space_chunks(
     ``start_chunk`` skips the first N chunks of the walk (counted in
     yield order) without decoding them — whole model/group segments are
     skipped by chunk-count arithmetic, so resume cost is O(max_points)
-    index bookkeeping, never re-evaluation.  ``telemetry=`` reaches
-    ``space_points``.
+    index bookkeeping, never re-evaluation.
     """
     a = space_size(space)
     n = joint_space_size(space, num_models)
@@ -397,7 +438,7 @@ def iter_joint_space_chunks(
                 continue
             for lo in range(skip * chunk_size, len(midx), chunk_size):
                 idx = midx[lo:lo + chunk_size]
-                yield m, space_points(idx - m * a, space, telemetry), idx
+                yield m, space_columns(idx - m * a, space), idx
             skip = 0
         return
     if model_groups is None:
@@ -417,8 +458,7 @@ def iter_joint_space_chunks(
             for lo in range(skip * chunk_size, g_n, chunk_size):
                 loc = np.arange(lo, min(lo + chunk_size, g_n), dtype=np.int64)
                 mids = g[loc // a]
-                yield (mids, space_points(loc % a, space, telemetry),
-                       mids * a + loc % a)
+                yield mids, space_columns(loc % a, space), mids * a + loc % a
             skip = 0
         else:
             gidx = keep[np.isin(keep // a, g)]
@@ -428,7 +468,7 @@ def iter_joint_space_chunks(
                 continue
             for lo in range(skip * chunk_size, len(gidx), chunk_size):
                 idx = gidx[lo:lo + chunk_size]
-                yield idx // a, space_points(idx % a, space, telemetry), idx
+                yield idx // a, space_columns(idx % a, space), idx
             skip = 0
 
 

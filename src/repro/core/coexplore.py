@@ -241,11 +241,10 @@ def _fold_joint(tr, archive, best: dict, models: tuple,
     return obj, m_idx
 
 
-def _pe_codes(tr, cfg) -> np.ndarray:
-    """A decoded chunk's PE-type codes read back from the device (span
-    ``copy.codes``)."""
-    with tr.span("codes", cat="copy"):
-        return np.asarray(cfg.pe_type).astype(np.int64)
+def _pe_codes(cfg: AcceleratorConfig) -> np.ndarray:
+    """A decoded chunk's PE-type codes, from its host columns: nothing is
+    read back from the device."""
+    return cfg.pe_type.astype(np.int64)
 
 
 def _bucket_models(models: tuple, layer_buckets):
@@ -312,18 +311,18 @@ class JointWalk(NamedTuple):
     local: np.ndarray              # global id -> position in its stack
     buckets_meta: tuple = ()       # mixed: (padded depth, model names)
 
-    def chunks(self, start_chunk: int = 0, telemetry=None):
+    def chunks(self, start_chunk: int = 0):
         """Yield ``(wl_key, workload, model_ids, mids, cfg, idx)`` from
         ``start_chunk`` on — resumable by index arithmetic, identical
         sequences across drivers.  ``wl_key`` names the workload (bucket
         depth when mixing, model id otherwise) for pruner/checkpoint
-        state.  ``telemetry=`` reaches the decode's ``space_points``."""
+        state; ``cfg`` holds the chunk's host columns."""
         if self.mix_models:
             for mids, cfg, idx in iter_joint_space_chunks(
                     self.space, num_models=len(self.models),
                     chunk_size=self.chunk_size, max_points=self.max_points,
                     seed=self.seed, model_groups=self.group_ids,
-                    start_chunk=start_chunk, telemetry=telemetry):
+                    start_chunk=start_chunk):
                 b = self.bucket_of[int(mids[0])]
                 yield b, self.stacked[b], self.local[mids], mids, cfg, idx
             return
@@ -331,7 +330,7 @@ class JointWalk(NamedTuple):
                 self.space, num_models=len(self.models),
                 chunk_size=self.chunk_size, max_points=self.max_points,
                 seed=self.seed, group_by_model=True,
-                start_chunk=start_chunk, telemetry=telemetry):
+                start_chunk=start_chunk):
             mids = np.full(len(idx), int(m), np.int64)
             yield (int(m), self.stacked[self.bucket_of[m]],
                    self.local[mids], mids, cfg, idx)
@@ -554,8 +553,8 @@ def coexplore_front(
                 _fold_flush(*out)
 
     for _, wl, model_ids, mids, cfg, idx in timed_iter(
-            walk.chunks(telemetry=tr), tr):
-        _feed(cfg, idx, wl, mids, _pe_codes(tr, cfg), model_ids=model_ids)
+            walk.chunks(), tr):
+        _feed(cfg, idx, wl, mids, _pe_codes(cfg), model_ids=model_ids)
     _finish_walk()
     return CoexploreFront(archive=archive, models=models, space=space,
                           metrics=COEXPLORE_METRICS,
@@ -728,12 +727,12 @@ def _sharded_coexplore_front(
 
     t_disp: dict[int, int] = {}
     for c, (wl_key, wl, model_ids, mids, cfg, idx) in enumerate(
-            timed_iter(walk.chunks(start, telemetry=tr), tr), start=start):
+            timed_iter(walk.chunks(start), tr), start=start):
         if max_chunks is not None and c - start >= max_chunks:
             completed = False
             break
         s = c % n_shards
-        codes = _pe_codes(tr, cfg)
+        codes = _pe_codes(cfg)
         if traced:
             _count_chunk(tr, len(idx), wl)
         if engage:

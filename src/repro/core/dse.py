@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.arch import (AcceleratorConfig, PE_INT16, PE_TYPE_NAMES,
+                             as_config, pack_config,
                              concat_configs, iter_space_chunks, space_points,
                              take_config)
 from repro.core.constraints import (Budget, BudgetStats, apply_budget,
@@ -181,7 +182,8 @@ def _note_compiles(tr, mark: int, start_ns: int, workload,
 
 def _traced_dispatch(tr, cfg, workload, model, pad_to, model_ids=None,
                      track: str | None = None) -> "PendingChunk":
-    """``dispatch_chunk`` under a ``dispatch`` span + compile detection."""
+    """``dispatch_chunk`` under a ``dispatch`` span (its upload nested as
+    ``copy.upload``) + compile detection."""
     if not tr.enabled:
         return dispatch_chunk(cfg, workload, model, pad_to=pad_to,
                               model_ids=model_ids)
@@ -189,7 +191,7 @@ def _traced_dispatch(tr, cfg, workload, model, pad_to, model_ids=None,
     t0 = time.perf_counter_ns()
     with tr.span("dispatch", track=track):
         pending = dispatch_chunk(cfg, workload, model, pad_to=pad_to,
-                                 model_ids=model_ids)
+                                 model_ids=model_ids, telemetry=tr)
     _note_compiles(tr, mark, t0, workload, track=track)
     return pending
 
@@ -234,20 +236,27 @@ def _network_sums(cfg: AcceleratorConfig, clock_ghz: jnp.ndarray,
     shape) — mixed-model, per-model and plain walks alike — so walks that
     visit the same lane compute it with the same compiled code."""
     _count_trace()
+    cfg = as_config(cfg)
     per_layer = jax.vmap(
         lambda lay, c, clk: jax.vmap(layer_cost, in_axes=(0, None, None))(
             lay, c, clk))(lane_layers, cfg, clock_ghz)  # leaves (lanes, L)
     return reduce_layer_costs(per_layer, lane_layers.count, barrier=True)
 
 
+@jax.jit
+def _stack_results(*cols):
+    """The results ``_finish`` reads as one (9, lanes) float32 block, so
+    the host reads one buffer instead of nine."""
+    return jnp.stack(cols)
+
+
 def _fetch(cost, clock_ghz, area_mm2, leak_mw) -> tuple:
     """The nine device results ``_finish`` reads, copied to host float64
-    one after another in a fixed order."""
-    f64 = lambda x: np.asarray(x, np.float64)  # noqa: E731
-    return (f64(cost.cycles), f64(cost.utilization), f64(cost.macs),
-            f64(cost.energy_mac_pj), f64(cost.energy_mem_pj),
-            f64(cost.energy_dram_pj), f64(clock_ghz), f64(area_mm2),
-            f64(leak_mw))
+    in ONE device-to-host transfer, in a fixed order."""
+    block = _stack_results(cost.cycles, cost.utilization, cost.macs,
+                           cost.energy_mac_pj, cost.energy_mem_pj,
+                           cost.energy_dram_pj, clock_ghz, area_mm2, leak_mw)
+    return tuple(np.asarray(block, np.float64))
 
 
 def _finish(cycles, util, macs, e_mac, e_mem, e_dram, clock_ghz,
@@ -291,7 +300,7 @@ def _finish(cycles, util, macs, e_mac, e_mem, e_dram, clock_ghz,
 @partial(jax.jit, static_argnums=0)
 def _ppa_stage(ppa_fn, params, cfg: AcceleratorConfig):
     _count_ppa_trace()
-    power_mw, clock_ghz, area_mm2 = ppa_fn(params, cfg)
+    power_mw, clock_ghz, area_mm2 = ppa_fn(params, as_config(cfg))
     return power_mw, clock_ghz, area_mm2, LEAKAGE_MW_PER_MM2 * area_mm2
 
 
@@ -306,7 +315,7 @@ def _network_stage(cfg: AcceleratorConfig, clock_ghz,
         lanes = _lane_layers(workload.layers, model_ids)
     else:
         lanes = _broadcast_layers(workload.layers,
-                                  int(np.shape(cfg.pe_rows)[0]),
+                                  int(np.shape(cfg.pe_type)[0]),
                                   layer_bucket(workload_layers(workload)))
     return _network_sums(cfg, clock_ghz, lanes)
 
@@ -329,12 +338,23 @@ def _pad_config(cfg: AcceleratorConfig, pad: int) -> AcceleratorConfig:
     """Repeat the last design point ``pad`` times so the chunk shape is
     fixed — padded lanes are sliced off after evaluation.  Host numpy:
     padding happens on every trailing partial chunk and eager device
-    concatenates cost more than the whole jit dispatch."""
+    concatenates cost more than the whole jit dispatch; columns still on
+    the device come back in one batched read."""
     return AcceleratorConfig(*[
-        np.concatenate([np.asarray(f),
-                        np.broadcast_to(np.asarray(f)[-1:],
-                                        (pad,) + np.shape(f)[1:])])
-        for f in cfg])
+        np.concatenate([f, np.broadcast_to(f[-1:], (pad,) + f.shape[1:])])
+        for f in map(np.asarray, jax.device_get(cfg))])
+
+
+def _upload(cfg: AcceleratorConfig, *lanes, telemetry=None):
+    """A chunk's stage inputs on the device in ONE transfer, under a
+    ``copy.upload`` span: the config packed (``pack_config``: two buffers,
+    not nine) with any per-lane host arrays (``None`` stays ``None``).
+    Returns ``(packed_cfg, *lanes)``.  Every stage call of the engine
+    takes its config from here, so each stage compiles one executable
+    per chunk shape."""
+    cfg = pack_config(cfg)
+    with as_tracer(telemetry).span("upload", cat="copy"):
+        return jax.device_put((cfg, *lanes))
 
 
 def _slice_config(cfg: AcceleratorConfig, lo: int, hi: int) -> AcceleratorConfig:
@@ -382,16 +402,18 @@ def dispatch_chunk(cfg: AcceleratorConfig,
                    workload: Workload | StackedWorkload,
                    surrogate: PPAModels | CostModel | str | None = None,
                    pad_to: int | None = None,
-                   model_ids=None) -> PendingChunk:
-    """The non-blocking half of ``evaluate_chunk``: validate, pad and
-    DISPATCH the jitted stages, returning device futures immediately.
+                   model_ids=None, telemetry=None) -> PendingChunk:
+    """The non-blocking half of ``evaluate_chunk``: validate, pad, upload
+    and DISPATCH the jitted stages, returning device futures immediately.
 
     JAX dispatches asynchronously, so control returns while the chunk
     still computes — the caller can dispatch the next chunk (on another
     device) or do host-side archive work before blocking in
     ``finish_chunk``.  ``finish_chunk(dispatch_chunk(...))`` is exactly
     ``evaluate_chunk(...)``; the split exists so the sharded walk can
-    double-buffer.
+    double-buffer.  A host config (the chunk iterators') is padded on the
+    host and goes up with the lanes' model positions in one transfer
+    (``_upload``; ``telemetry=`` times it as ``copy.upload``).
     """
     stacked = isinstance(workload, StackedWorkload)
     if stacked != (model_ids is not None):
@@ -420,11 +442,11 @@ def dispatch_chunk(cfg: AcceleratorConfig,
         if mids is not None:  # padded lanes repeat the last (model, config)
             mids = np.concatenate([mids, np.broadcast_to(mids[-1:],
                                                          (pad_to - n,))])
+    cfg, mids = _upload(cfg, mids, telemetry=telemetry)
     power, clock, area, leak = _ppa_stage(model.ppa_fn, model.ppa_params, cfg)
     del power  # nominal-activity power; the result's power column is
     #            derived from chip energy over runtime in _finish
-    cost = _network_stage(cfg, clock, workload,
-                          None if mids is None else jnp.asarray(mids))
+    cost = _network_stage(cfg, clock, workload, mids)
     return PendingChunk(cost, clock, area, leak, n)
 
 
@@ -636,11 +658,11 @@ class TwoStagePruner:
             self.model.validate(cfg)
             cfg_p = _pad_config(cfg, self.chunk_size - n) \
                 if n < self.chunk_size else cfg
+            cfg_p, = _upload(cfg_p, telemetry=self._tr)
             _, clock, area, leak = _ppa_stage(self.model.ppa_fn,
                                               self.model.ppa_params, cfg_p)
-            clock = np.asarray(clock)[:n]
-            area = np.asarray(area)[:n]
-            leak = np.asarray(leak)[:n]
+            clock, area, leak = (np.asarray(x)[:n] for x in
+                                 jax.device_get((clock, area, leak)))
             accuracy = None if aux is None else aux.get("accuracy")
             mask, kills = self.budget.feasibility(
                 _PPAView(area_mm2=area), accuracy=accuracy,
@@ -774,9 +796,10 @@ class TwoStagePruner:
         mark = _compile_mark()
         t0 = time.perf_counter_ns()
         with self._tr.span("prune_stage2", track=self._track):
-            cost = _network_stage(cfg, jnp.asarray(clock), self._workload,
-                                  None if mids is None else jnp.asarray(mids))
-            full = _finish(*_fetch(cost, clock, area, leak))
+            cfg_d, *lanes_d, mids_d = _upload(cfg, clock, area, leak, mids,
+                                              telemetry=self._tr)
+            cost = _network_stage(cfg_d, lanes_d[0], self._workload, mids_d)
+            full = _finish(*_fetch(cost, *lanes_d))
         _note_compiles(self._tr, mark, t0, self._workload, track=self._track)
         res = DseResult(*[np.asarray(col[:n], RESULT_DTYPES[f])
                           for f, col in zip(DseResult._fields, full)])
@@ -832,6 +855,7 @@ def evaluate_space(cfg: AcceleratorConfig, workload: Workload,
         pad = _next_pow2(n) if chunk_size is None \
             else min(chunk_size, _next_pow2(n))
         return evaluate_chunk(cfg, workload, surrogate, pad_to=pad)
+    cfg = jax.device_get(cfg)  # one batched read; chunks slice on host
     cols: list[list[np.ndarray]] = [[] for _ in DseResult._fields]
     for lo in range(0, n, chunk_size):
         res = evaluate_chunk(_slice_config(cfg, lo, min(lo + chunk_size, n)),
@@ -909,8 +933,7 @@ def evaluate_space_streaming(
                                 telemetry=telemetry)
         for cfg, idx in timed_iter(
                 iter_space_chunks(space, chunk_size=chunk_size,
-                                  max_points=max_points, seed=seed,
-                                  telemetry=telemetry), tr):
+                                  max_points=max_points, seed=seed), tr):
             if tr.enabled:
                 _count_chunk(tr, len(idx), workload)
             for res, fidx, _aux in pruner.feed(cfg, idx, workload):
@@ -920,8 +943,7 @@ def evaluate_space_streaming(
         return
     for cfg, idx in timed_iter(
             iter_space_chunks(space, chunk_size=chunk_size,
-                              max_points=max_points, seed=seed,
-                              telemetry=telemetry), tr):
+                              max_points=max_points, seed=seed), tr):
         n_raw = len(idx)
         if tr.enabled:
             _count_chunk(tr, n_raw, workload)

@@ -40,7 +40,8 @@ import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 import numpy as np
 
-from repro.core.arch import AcceleratorConfig, PE_TYPE_NAMES
+from repro.core.arch import (AcceleratorConfig, PE_TYPE_NAMES, PackedConfig,
+                             pack_config)
 from repro.core.synth import LEAKAGE_MW_PER_MM2, SynthResult, synthesize
 
 # Regression features: every knob except pe_type (models are per PE type).
@@ -399,11 +400,13 @@ class PPAModels:
         params = self.ppa_params()
         n = np.shape(np.asarray(cfg.pe_type))[0] \
             if np.ndim(cfg.pe_type) else 1
+        packed = pack_config(cfg)   # the config form every stage call takes
         if n <= _PREDICT_CHUNK:
-            power, clock, area, leak = ppa_stage(surrogate_ppa, params, cfg)
+            power, clock, area, leak = ppa_stage(surrogate_ppa, params, packed)
         else:
-            parts = [ppa_stage(surrogate_ppa, params, AcceleratorConfig(
-                *[f[lo:lo + _PREDICT_CHUNK] for f in cfg]))
+            parts = [ppa_stage(surrogate_ppa, params, PackedConfig(
+                packed.knobs[:, lo:lo + _PREDICT_CHUNK],
+                packed.pe_type[lo:lo + _PREDICT_CHUNK]))
                 for lo in range(0, n, _PREDICT_CHUNK)]
             power, clock, area, leak = (jnp.concatenate(cols)
                                         for cols in zip(*parts))

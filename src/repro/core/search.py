@@ -47,7 +47,7 @@ import jax
 import numpy as np
 
 from repro.core.accuracy import AccuracySurrogate
-from repro.core.arch import (joint_space_size, space_points, space_radices,
+from repro.core.arch import (joint_space_size, space_columns, space_radices,
                              space_size)
 from repro.core.constraints import Budget, BudgetStats
 from repro.core.costmodel import CostModel, as_cost_model
@@ -55,7 +55,7 @@ from repro.core.coexplore import (COEXPLORE_METRICS, CoexploreFront,
                                   ModelEntry, _fold_joint, _pe_codes,
                                   accuracy_matrix, plan_joint_walk)
 from repro.core.dse import (DEFAULT_CHUNK_SIZE, ParetoArchive, _PPAView,
-                            _pad_config, _ppa_stage, _stage_depth,
+                            _pad_config, _ppa_stage, _stage_depth, _upload,
                             _traced_dispatch, _traced_finish, dispatch_chunk,
                             finish_chunk)
 from repro.core.ppa import PPAModels
@@ -465,13 +465,15 @@ def _make_screen(models, space, cost_model, acc_matrix, budget, chunk_size,
         with tr.span("screen", cat="search"):
             for lo in range(0, len(idx), chunk_size):
                 part = idx[lo:lo + chunk_size]
-                cfg = space_points(part % accel_size, space)
+                cols = space_columns(part % accel_size, space)
                 n = len(part)
                 if n < chunk_size:
-                    cfg = _pad_config(cfg, chunk_size - n)
+                    cols = _pad_config(cols, chunk_size - n)
+                cfg, = _upload(cols)
                 power, clock, area, _leak = _ppa_stage(
                     cost_model.ppa_fn, cost_model.ppa_params, cfg)
-                codes_all.append(np.asarray(cfg.pe_type, np.int64)[:n])
+                power, clock, area = jax.device_get((power, clock, area))
+                codes_all.append(_pe_codes(cols)[:n])
                 areas.append(np.asarray(area, np.float64)[:n])
                 clocks.append(np.asarray(clock, np.float64)[:n])
                 powers.append(np.asarray(power, np.float64)[:n])
@@ -480,7 +482,7 @@ def _make_screen(models, space, cost_model, acc_matrix, budget, chunk_size,
         clock = np.concatenate(clocks)
         power = np.concatenate(powers)
         lane_acc = acc_matrix[mids, codes]
-        cfg_cols = space_points(idx % accel_size, space)
+        cfg_cols = space_columns(idx % accel_size, space)
         num_pes = (np.asarray(cfg_cols.pe_rows, np.float64)
                    * np.asarray(cfg_cols.pe_cols, np.float64))
         peak = clock * 1e9 * num_pes / np.maximum(area, 1e-9)
@@ -665,8 +667,8 @@ def search_front(
                 idx = g_idx[lo:lo + chunk_size]
                 with tr.span("decode", cat="search"):
                     mids = idx // accel
-                    cfg = space_points(idx % accel, space, tr)
-                    codes = _pe_codes(tr, cfg)
+                    cfg = space_columns(idx % accel, space)
+                    codes = _pe_codes(cfg)
                     model_ids = walk.local[mids]
                 with jax.default_device(
                         _shard.shard_device(devs, c % n_shards)):

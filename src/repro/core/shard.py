@@ -359,7 +359,7 @@ def _sharded_space_events(
     chunks = timed_iter(
         iter_space_chunks(space, chunk_size=chunk_size,
                           max_points=max_points, seed=seed,
-                          start_chunk=start_chunk, telemetry=tracer), tracer)
+                          start_chunk=start_chunk), tracer)
     for c, (cfg, idx) in enumerate(chunks, start=start_chunk):
         if max_chunks is not None and c - start_chunk >= max_chunks:
             completed = False

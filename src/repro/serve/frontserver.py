@@ -77,7 +77,7 @@ import jax
 import numpy as np
 
 from repro.core.coexplore import (COEXPLORE_METRICS, CoexploreFront,
-                                  ModelEntry, _joint_objectives,
+                                  ModelEntry, _joint_objectives, _pe_codes,
                                   accuracy_matrix, plan_joint_walk)
 from repro.core.constraints import Budget, BudgetColumns, BudgetStats
 from repro.core.costmodel import as_cost_model
@@ -573,7 +573,7 @@ class FrontServer:
                 break
             _, wl, model_ids, mids, cfg, idx = nxt
             walk.started = True
-            codes = np.asarray(cfg.pe_type).astype(np.int64)
+            codes = _pe_codes(cfg)
             if tr.enabled:
                 _count_chunk(tr, len(idx), wl)
             pending = _traced_dispatch(tr, cfg, wl, self._model,

@@ -17,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import dse, ppa
-from repro.core.arch import AcceleratorConfig
+from repro.core.arch import KNOB_FIELDS, PackedConfig
 from repro.core.synth import oracle_ppa
 from repro.core.workloads import LayerSpec
 from repro.kernels.fake_quant.fake_quant import fake_quant
@@ -58,9 +58,9 @@ def _compile(fn, *args):
 
 
 def _config(sharding):
-    return AcceleratorConfig(*[
-        _spec(sharding, (CHUNK,), jnp.int32 if f == "pe_type"
-              else jnp.float32) for f in AcceleratorConfig._fields])
+    """A chunk's config as the engine's stages receive it (packed)."""
+    return PackedConfig(_spec(sharding, (len(KNOB_FIELDS), CHUNK)),
+                        _spec(sharding, (CHUNK,), jnp.int32))
 
 
 def test_ppa_stage(one_chip):
@@ -81,6 +81,12 @@ def test_network_sums_64_layer_bucket(one_chip):
     clock = _spec(one_chip, (CHUNK,))
     assert _compile(dse._network_sums.__wrapped__, _config(one_chip), clock,
                     lanes)
+
+
+def test_result_block(one_chip):
+    """The nine result columns stacked for the finish's one read."""
+    cols = [_spec(one_chip, (CHUNK,)) for _ in range(9)]
+    assert _compile(dse._stack_results.__wrapped__, *cols)
 
 
 def test_surrogate_fit_degree_3(one_chip):

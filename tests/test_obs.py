@@ -664,7 +664,7 @@ def _search(models, telemetry=None, seed=1):
     return front, drv.seen
 
 
-PER_CHUNK = ("copy.upload", "copy.codes", "copy.fetch", "sweep.dispatch",
+PER_CHUNK = ("copy.upload", "copy.fetch", "sweep.dispatch",
              "sweep.objectives", "sweep.archive", "archive.prefilter",
              "sweep.best")
 
@@ -732,11 +732,10 @@ class TestChunkPathSpans:
         assert 0 < surv.value <= 2 * fronts[0].points_evaluated
         assert _parents(tr) == {
             "sweep.walk_setup": None, "sweep.decode": None,
-            "copy.upload": "sweep.decode", "copy.codes": None,
-            "sweep.dispatch": None, "sweep.device_wait": None,
-            "copy.fetch": "sweep.device_wait", "sweep.objectives": None,
-            "sweep.archive": None, "archive.prefilter": "sweep.archive",
-            "sweep.best": None}
+            "sweep.dispatch": None, "copy.upload": "sweep.dispatch",
+            "sweep.device_wait": None, "copy.fetch": "sweep.device_wait",
+            "sweep.objectives": None, "sweep.archive": None,
+            "archive.prefilter": "sweep.archive", "sweep.best": None}
         top = c[TOP_LEVEL_COUNTER].value
         assert 0.5 * wall < top <= wall
 
@@ -752,8 +751,8 @@ class TestChunkPathSpans:
         assert h["search.partition"].count == c["search.generations"].value
         assert c["archive.survivors"].count == chunks
         parents = _parents(tr)
-        assert parents["copy.upload"] == "search.decode"
-        assert parents["copy.codes"] == "search.decode"
+        assert parents["copy.upload"] == "sweep.dispatch"
+        assert "copy.codes" not in parents   # the PE codes stay on the host
         assert parents["copy.fetch"] == "sweep.device_wait"
         assert parents["archive.prefilter"] == "sweep.archive"
         for name in ("search.setup", "search.propose", "search.partition",
@@ -935,12 +934,12 @@ class TestBenchReaders:
         per_chunk = lambda *ns: sum(r.span_s(n) for n in ns) \
             / r.chunks * 1e3  # noqa: E731
         assert read["copy_ms_per_chunk.sweep"] == pytest.approx(
-            per_chunk("copy.upload", "copy.codes", "copy.fetch"))
+            per_chunk("copy.upload", "copy.fetch"))
         assert read["archive_survivors_per_chunk.sweep"] == pytest.approx(
             r.counters["archive.survivors"] / r.chunks)
-        # copies of the search sit inside its decode and finish spans
+        # copies of the search sit inside its dispatch and finish spans
         assert read["copy_ms_per_chunk.sweep"] <= per_chunk(
-            "search.decode", "sweep.device_wait")
+            "sweep.dispatch", "sweep.device_wait")
         assert read["unspanned_ms_per_chunk.sweep"] == pytest.approx(
             (r.trace["window_s"] - r.counters[TOP_LEVEL_COUNTER])
             / r.chunks * 1e3)
